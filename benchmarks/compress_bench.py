@@ -41,6 +41,7 @@ from repro.fed.api import CompressionSpec, FedSpec, build_trainer
 from repro.fed.compress import (PALLAS_COMPRESSORS, available_compressors,
                                 compress_increment, get_compressor)
 from repro.fed.engine import RoundConfig
+from repro.kernels.compress.ops import fits_vmem
 
 # bits per transmitted value on the wire (topk adds ~log2(m) index bits,
 # folded into the measured keep fraction's 32-bit values below)
@@ -123,6 +124,8 @@ def _perf(quick):
                 for i, w in enumerate(widths)}
         m_total = int(sum(widths))
         for name in sorted(PALLAS_COMPRESSORS):
+            if not fits_vmem(name, m_total):
+                continue       # no kernel at this width: nothing to compare
             ms = {}
             for backend in ("xla", "pallas"):
                 cfg = RoundConfig(

@@ -116,10 +116,8 @@ def _rounds(quick):
     cases = [
         ("baseline", dict(), 1.0),
         ("pallas_fused", dict(use_pallas=True), 1.0),
-        # compress backends pinned to "xla": the CompressionSpec default
-        # is now "auto", which would fold adaptive into adaptive_pallas
-        # and make int8 width-dependent -- these rows track the per-leaf
-        # path
+        # compress backends pinned to "xla": the kernels' whole-row
+        # blocks do not fit VMEM at model width (fed/compress.py)
         ("topk50", dict(compression=CompressionSpec(
             "topk", 0.5, backend="xla")), 2.0),
         ("topk25", dict(compression=CompressionSpec(
@@ -129,11 +127,6 @@ def _rounds(quick):
         ("adaptive", dict(compression=CompressionSpec(
             "adaptive_topk", ratio=0.25, energy=0.9,
             backend="xla")), 4.0),
-        # same compressor through the packed fused-kernel path: one
-        # launch for the whole pytree, one sort instead of two per leaf
-        ("adaptive_pallas", dict(compression=CompressionSpec(
-            "adaptive_topk", ratio=0.25, energy=0.9,
-            backend="pallas")), 4.0),
         # heterogeneous groups: half the agents run AGD, half run one
         # cheap GD epoch -- measures the sequential group-dispatch cost
         ("hetero_gd_agd", dict(

@@ -24,6 +24,7 @@ from benchmarks import (compress_bench, engine_bench, kernel_bench,
                         table3_tc_sweep, table4_solvers_pp, table5_large_n,
                         table6_participation, table7_privacy_noise,
                         table8_rho, table9_ne)
+from repro.launch.cache import enable_compile_cache
 
 MODULES = {
     "table2": table2_comparison,
@@ -43,6 +44,7 @@ MODULES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="more Monte-Carlo seeds (slower)")

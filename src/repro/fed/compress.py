@@ -33,14 +33,11 @@ per-leaf XLA path (asserted in tests).  Compressors without a kernel
 (custom registry entries, ``none``) fall back to the per-leaf XLA path
 under either backend.
 
-``"auto"`` (the :class:`repro.fed.api.CompressionSpec` default) picks
-per call from the committed BENCH_compress.json evidence
-(:func:`resolve_backend`): the fused kernel always wins for
-``adaptive_topk`` (it replaces two XLA sorts per leaf with one counting
-pass), always loses for static ``topk`` on this container (XLA's
-``top_k`` beats the full sort), and pays off for ``int8`` only on wide
-buffers where the scale reduction amortizes the launch.  Both backends
-are bit-identical, so auto-dispatch is a pure scheduling choice --
+``"auto"`` (the :class:`repro.fed.api.CompressionSpec` default) takes
+the fused kernel exactly where its whole-row block fits the chip's fast
+memory (:func:`resolve_backend`) -- the dense paper problems, never a
+packed model row -- and the registry path elsewhere.  Both backends are
+bit-identical, so auto-dispatch is a pure scheduling choice --
 trajectories do not depend on it.
 """
 
@@ -50,6 +47,8 @@ from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.compress.kernel import prefix_sum
 
 # (dz_rows (N, m), round_cfg) -> transmitted rows (N, m)
 CompressFn = Callable[[jnp.ndarray, Any], jnp.ndarray]
@@ -62,10 +61,6 @@ PALLAS_COMPRESSORS = frozenset({"topk", "adaptive_topk", "int8"})
 
 # column alignment of the packed buffer (TPU lane width)
 _LANE = 128
-
-# auto-dispatch: int8's fused kernel only amortizes its launch on wide
-# buffers (BENCH_compress.json: 0.29x at m=256, 1.1-1.3x at m >= 65536)
-_AUTO_INT8_MIN_COLS = 16384
 
 
 def register_compressor(name: str) -> Callable[[CompressFn], CompressFn]:
@@ -101,29 +96,35 @@ def _backend_of(cfg) -> str:
 
 def resolve_backend(cfg, m_total=None) -> str:
     """Resolve ``cfg.compress_backend`` to a concrete ``"xla"`` /
-    ``"pallas"`` for this ``(n_agents, m_total, compressor)`` case.
+    ``"pallas"`` for a buffer ``m_total`` columns wide.
 
-    Explicit backends pass through.  ``"auto"`` encodes the committed
-    BENCH_compress.json evidence: ``adaptive_topk`` always takes the
-    fused kernel (4-9x: one counting pass vs two XLA sorts per leaf),
-    static ``topk`` always takes XLA (``lax.top_k`` beats a full sort at
-    every measured shape), and ``int8`` takes the kernel only at
-    ``m_total >= _AUTO_INT8_MIN_COLS`` where the per-(agent, segment)
-    scale reduction amortizes the launch.  Both backends are
-    bit-identical, so this is purely a scheduling decision.
+    The kernels hold whole ``(rows, m_total)`` rows in one block, so they
+    exist only where that block fits the chip's fast memory
+    (:func:`repro.kernels.compress.ops.fits_vmem`).  ``"auto"`` takes the
+    kernel exactly there, and XLA elsewhere or when the width is
+    unknown; an explicit ``"pallas"`` beyond it raises instead of
+    falling back.  Both backends are bit-identical, so this is purely a
+    scheduling decision.  Compressors without a kernel always resolve
+    to the registry path.
     """
+    from repro.kernels.compress.ops import (VMEM_LIMIT_BYTES,
+                                            block_vmem_bytes, fits_vmem)
+
     backend = _backend_of(cfg)
-    if backend != "auto":
-        return backend
     name = cfg.compression
-    if name not in PALLAS_COMPRESSORS:
-        return "xla"          # no kernel: only the registry path exists
-    if name == "adaptive_topk":
-        return "pallas"
-    if name == "int8" and m_total is not None \
-            and m_total >= _AUTO_INT8_MIN_COLS:
-        return "pallas"
-    return "xla"
+    if name not in PALLAS_COMPRESSORS or backend == "xla":
+        return "xla" if backend == "auto" else backend
+    fits = m_total is not None and fits_vmem(name, m_total)
+    if backend == "pallas" and m_total is not None and not fits:
+        raise ValueError(
+            f"compress backend 'pallas': the {name} kernel keeps whole "
+            f"{m_total}-column rows in one block, about "
+            f"{block_vmem_bytes(name, m_total) / 2**20:.0f} MiB of VMEM "
+            f"(limit {VMEM_LIMIT_BYTES / 2**20:.0f} MiB); use backend "
+            f"'auto' or 'xla'")
+    if backend == "auto":
+        return "pallas" if fits else "xla"
+    return backend
 
 
 def _use_pallas(cfg, m_total=None) -> bool:
@@ -227,10 +228,34 @@ def pack_leaves(tree: Any) -> Tuple[jnp.ndarray, PackedMeta]:
     # (O(leaves x M_total) traffic -- ~20x slower at a 200-leaf
     # engine-scale tree), while consecutive dynamic_update_slice
     # ops alias in place under jit
+    if _lane_rows(meta, meta.width):
+        rows = jnp.zeros((n, meta.width // _LANE, _LANE), leaves[0].dtype)
+        for f, (s0, _) in zip(flat, meta.segments):
+            rows = jax.lax.dynamic_update_slice(
+                rows, f.reshape(n, -1, _LANE), (0, s0 // _LANE, 0))
+        return (jax.lax.optimization_barrier(rows).reshape(n, meta.width),
+                meta)
     buf = jnp.zeros((n, meta.width), leaves[0].dtype)
     for f, (s0, _) in zip(flat, meta.segments):
         buf = jax.lax.dynamic_update_slice(buf, f, (0, s0))
     return buf, meta
+
+
+def _lane_rows(meta: PackedMeta, width: int) -> bool:
+    """Whether every leaf starts and ends on a 128-column boundary, so
+    that :func:`pack_leaves` / :func:`unpack_leaves` move whole rows of
+    a ``(n, width/128, 128)`` view, held apart from the ``(n, width)``
+    buffer by a barrier.
+
+    On a TPU the ``(n, width)`` buffer tiles the agent axis into its
+    minor tile.  Reshaped straight into (or out of) model-shaped leaves,
+    XLA keeps that layout through the model and lowers the vmapped
+    matmuls over the agent axis as dilated convolutions whose compile
+    time grows with the weights: a 2-layer phi4-mini-width round with a
+    50k-row vocabulary compiled for a v5e in ~500 s instead of the tree
+    layout's ~15 s.  The row view puts the agent axis major."""
+    return width % _LANE == 0 and all(
+        s % _LANE == 0 for seg in meta.segments for s in seg)
 
 
 def unpack_leaves(buf: jnp.ndarray, meta: PackedMeta) -> Any:
@@ -240,8 +265,14 @@ def unpack_leaves(buf: jnp.ndarray, meta: PackedMeta) -> Any:
     buffer (a heterogeneous solver group's agents) unpacks with the same
     meta."""
     n = buf.shape[0]
-    leaves = [buf[:, s0:s1].reshape((n,) + shape[1:])
-              for (s0, s1), shape in zip(meta.segments, meta.shapes)]
+    if _lane_rows(meta, buf.shape[1]):
+        rows = jax.lax.optimization_barrier(
+            buf.reshape(n, buf.shape[1] // _LANE, _LANE))
+        leaves = [rows[:, s0 // _LANE:s1 // _LANE].reshape((n,) + shape[1:])
+                  for (s0, s1), shape in zip(meta.segments, meta.shapes)]
+    else:
+        leaves = [buf[:, s0:s1].reshape((n,) + shape[1:])
+                  for (s0, s1), shape in zip(meta.segments, meta.shapes)]
     return jax.tree_util.tree_unflatten(meta.treedef, leaves)
 
 
@@ -396,7 +427,7 @@ def compress_adaptive_topk(dz: jnp.ndarray, cfg) -> jnp.ndarray:
     def row_fn(row):
         energy = jnp.square(jnp.abs(row))
         desc = jnp.sort(energy)[::-1]
-        cum = jnp.cumsum(desc)
+        cum = prefix_sum(desc)
         total = jnp.maximum(cum[-1], 1e-30)
         # smallest prefix capturing the energy target, never below the floor
         k = jnp.sum(cum < cfg.compress_energy * total) + 1

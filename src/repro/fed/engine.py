@@ -139,7 +139,7 @@ from typing import (Any, Callable, Dict, NamedTuple, Optional, Sequence,
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.fed import compress as compress_lib
@@ -627,16 +627,14 @@ def robust_seen(cfg: RoundConfig, z_seen: Any, live, meta=None,
         return survivor_mean_input(cfg, z_seen, live)
     from repro.fed import robust as robust_lib
 
-    if meta is not None:
-        col = None if mesh is None else _mesh_col_axis(
-            mesh, z_seen.shape[1])
-        return robust_lib.robust_seen_packed(
+    if meta is None:
+        return robust_lib.robust_seen_tree(
             z_seen, live, name=name, param=cfg.aggregator_param,
-            meta=meta, backend=cfg.engine_backend, mesh=mesh,
-            col_axis=col)
-    return robust_lib.robust_seen_tree(
+            backend=cfg.engine_backend, mesh=mesh)
+    col = None if mesh is None else _mesh_col_axis(mesh, z_seen.shape[1])
+    return robust_lib.robust_seen_packed(
         z_seen, live, name=name, param=cfg.aggregator_param,
-        backend=cfg.engine_backend)
+        meta=meta, backend=cfg.engine_backend, mesh=mesh, col_axis=col)
 
 
 def live_mask_rows(u: jnp.ndarray, live) -> jnp.ndarray:
@@ -766,7 +764,7 @@ def _uplink_sharded_xla(cfg: RoundConfig, z: jnp.ndarray,
     spec = P("agent", col)
     f = shard_map(body, mesh=mesh,
                   in_specs=(spec, spec) if lagged else (spec,),
-                  out_specs=(P(None, col), spec), check_rep=False)
+                  out_specs=(P(None, col), spec), check_vma=False)
     return f(z, z_seen) if lagged else f(z)
 
 
@@ -787,7 +785,7 @@ def _downlink_sharded_xla(cfg: RoundConfig, u: jnp.ndarray,
     spec = P("agent", col)
     f = shard_map(body, mesh=mesh,
                   in_specs=(P("agent"), spec, spec, spec, P(None, col)),
-                  out_specs=(spec, spec), check_rep=False)
+                  out_specs=(spec, spec), check_vma=False)
     return f(u.reshape(-1), w, x, z, y)
 
 
@@ -817,7 +815,7 @@ def _tree_uplink_sharded(cfg: RoundConfig, z: Any, z_seen: Any,
     f = shard_map(body, mesh=mesh,
                   in_specs=(rows, _row_specs(z_seen)) if lagged
                   else (rows,),
-                  out_specs=(y_specs, rows), check_rep=False)
+                  out_specs=(y_specs, rows), check_vma=False)
     return f(z, z_seen) if lagged else f(z)
 
 
@@ -844,7 +842,7 @@ def _tree_downlink_sharded(cfg: RoundConfig, u: jnp.ndarray, w: Any,
                   in_specs=(P("agent"), _row_specs(w), _row_specs(x),
                             _row_specs(z), _rep_specs(y)),
                   out_specs=(_row_specs(x), _row_specs(z)),
-                  check_rep=False)
+                  check_vma=False)
     return f(u.reshape(-1), w, x, z, y)
 
 

@@ -81,7 +81,7 @@ from typing import Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.fed import compress as compress_lib
@@ -291,18 +291,24 @@ def robust_seen_packed(z_seen: jnp.ndarray, live, *, name: str,
     spec = P("agent", col_axis)
     f = shard_map(body, mesh=mesh,
                   in_specs=(spec, P(), P(None, col_axis)),
-                  out_specs=spec, check_rep=False)
+                  out_specs=spec, check_vma=False)
     return f(z_seen, lv, jnp.asarray(cmask))
 
 
 def robust_seen_tree(z_seen, live, *, name: str, param: float,
-                     backend: str):
+                     backend: str, mesh=None):
     """Robust ``z_seen`` transform on agent-stacked pytrees: pack the
     leaves (fresh pack -- padding columns are exact zeros), aggregate,
     broadcast, unpack.  Real-column arithmetic is identical to the
     packed-resident path, so tree and packed trajectories stay
-    bitwise-aligned per realization (layout contract)."""
+    bitwise-aligned per realization (layout contract).  With a ``mesh``
+    the packed buffer takes the same ``shard_map`` all-gather path as
+    the packed layout (a Pallas kernel cannot be partitioned by GSPMD)."""
+    from repro.fed.engine import _mesh_col_axis
+
     buf, meta = compress_lib.pack_leaves(z_seen)
+    col = None if mesh is None else _mesh_col_axis(mesh, buf.shape[1])
     out = robust_seen_packed(buf, live, name=name, param=param,
-                             meta=meta, backend=backend)
+                             meta=meta, backend=backend, mesh=mesh,
+                             col_axis=col)
     return compress_lib.unpack_leaves(out, meta)

@@ -21,8 +21,10 @@ Each kernel ships as <name>/kernel.py (pl.pallas_call + BlockSpec),
   lru_scan        -- chunked diagonal linear recurrence (RG-LRU / mamba
                      time mixing) with sequential cross-chunk carry.
 
-This container is CPU-only: kernels are validated with interpret=True;
-on TPU set interpret=False (the default resolves via repro.kernels.ON_TPU).
+On the CPU (the test suite) kernels run with interpret=True; on a TPU
+the default ``interpret=None`` resolves to Mosaic via ``ON_TPU``, which
+is read once, at import.  ``chip_smoke.py`` fails if it is false on a
+TPU, and checks that its compiled rounds hold ``tpu_custom_call``.
 """
 
 import jax

@@ -37,8 +37,13 @@ magnitudes are obtained differs, and both give the IDENTICAL mask
                              unavailable); O(M log^2 M).
 
 (:func:`segment_ranks_2d` additionally materializes the int32 ranks by
-inverting the sort permutation with a batched scatter -- an
-introspection/test surface, interpret-oriented.)
+inverting the sort permutation with a second sort -- an
+introspection/test surface.)
+
+Prefix sums (the adaptive energy cumsum, the tie prefix) go through
+:func:`prefix_sum`, whose association is fixed by this module; the XLA
+compressors call the same function, so kernel and registry agree
+bitwise on every backend.
 
 All segment metadata (ids, starts, per-segment k) is static -- derived
 from the packed treedef at trace time -- so it is baked into the kernel
@@ -53,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_AGENTS = 8   # rows per grid program (the agent axis is small)
 
@@ -147,14 +153,59 @@ def _pad_cols(a, pad, fill):
         [a, jnp.full((a.shape[0], pad), fill, jnp.int32)], axis=1)
 
 
+def _lane_rolls(a, s):
+    """``(a[..., i - s], a[..., i + s])`` along the last axis, cyclic.
+
+    Two lane rotations; which one holds ``a[i - s]`` is read off a
+    rotated lane iota rather than assumed, so the result does not depend
+    on the rotate direction convention of the lowering."""
+    axis = a.ndim - 1
+    n = a.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, a.shape, axis)
+    fwd, bwd = pltpu.roll(a, s, axis), pltpu.roll(a, n - s, axis)
+    is_minus = pltpu.roll(idx, s, axis) == (idx - s) % n
+    return jnp.where(is_minus, fwd, bwd), jnp.where(is_minus, bwd, fwd)
+
+
 def _xor_shuffle(a, j):
     """``a[..., i ^ j]`` for a power-of-two stride ``j``: XOR with j
-    flips exactly one index bit, which is a static reshape + flip (no
-    gather -- Pallas kernels cannot capture index constants and Mosaic
-    has no general dynamic gather)."""
-    n = a.shape[-1]
-    v = a.reshape(a.shape[:-1] + (n // (2 * j), 2, j))
-    return jnp.flip(v, axis=-2).reshape(a.shape)
+    flips exactly one index bit, so the partner is ``i - j`` where that
+    bit is set and ``i + j`` where it is not -- two lane rotations and a
+    select (no gather: Pallas kernels cannot capture index constants,
+    and Mosaic lowers neither a general gather nor the ``rev`` a
+    reshape-and-flip would need)."""
+    minus, plus = _lane_rolls(a, j)
+    idx = jax.lax.broadcasted_iota(jnp.int32, a.shape, a.ndim - 1)
+    return jnp.where((idx & j) != 0, minus, plus)
+
+
+def prefix_sum(v, shift_right=None):
+    """Inclusive prefix sum along the last axis, Hillis-Steele order.
+
+    The association is fixed by this function (``log2 n`` passes of
+    ``v + v[i - s]``), not by a backend's ``cumsum`` lowering, so the
+    kernels (lane rotations, which Mosaic lowers; it has no ``cumsum``)
+    and the XLA compressors (zero-filled slices) that both call it add
+    the same operands in the same order and agree bitwise.
+    ``shift_right(v, s)`` returns ``v[..., i - s]`` with zeros for
+    ``i < s``; the default builds it from slices."""
+    if shift_right is None:
+        shift_right = _slice_shift_right
+    s = 1
+    while s < v.shape[-1]:
+        v = v + shift_right(v, s)
+        s *= 2
+    return v
+
+
+def _slice_shift_right(v, s):
+    pad = jnp.zeros(v.shape[:-1] + (s,), v.dtype)
+    return jnp.concatenate([pad, v[..., :-s]], axis=-1)
+
+
+def _lane_shift_right(v, s):
+    idx = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
+    return jnp.where(idx >= s, _lane_rolls(v, s)[0], 0).astype(v.dtype)
 
 
 def _bitonic_sort(arrs):
@@ -163,8 +214,8 @@ def _bitonic_sort(arrs):
     ``arrs`` is a tuple of int32 arrays compared lexicographically; the
     key must be unique per element (we always include the position), so
     the network realizes exactly the stable order.  Compare-exchange
-    partners and directions come from in-kernel iotas and static
-    reshapes -- the Mosaic-lowerable form.
+    partners and directions come from in-kernel iotas and lane
+    rotations -- the Mosaic-lowerable form.
     """
     n = arrs[0].shape[-1]
     if n & (n - 1):
@@ -172,21 +223,35 @@ def _bitonic_sort(arrs):
                          f"got {n}")
     idx = jax.lax.broadcasted_iota(jnp.int32, arrs[0].shape,
                                    arrs[0].ndim - 1)
-    k = 2
-    while k <= n:
-        j = k // 2
-        while j >= 1:
-            parrs = tuple(_xor_shuffle(a, j) for a in arrs)
-            ascending = (idx & k) == 0
-            is_left = (idx & j) == 0    # i < i ^ j  <=>  bit j unset
-            want_min = ascending == is_left
-            lt = _lex_lt(arrs, parrs)
-            take_partner = jnp.where(want_min, ~lt, lt)
-            arrs = tuple(jnp.where(take_partner, pa, a)
-                         for a, pa in zip(arrs, parrs))
-            j //= 2
-        k *= 2
-    return arrs
+
+    def stage(k, j, arrs):
+        parrs = tuple(_xor_shuffle(a, j) for a in arrs)
+        ascending = (idx & k) == 0
+        is_left = (idx & j) == 0        # i < i ^ j  <=>  bit j unset
+        want_min = ascending == is_left
+        # ``where(want_min, ~lt, lt)`` as an xor: Mosaic has no select
+        # over boolean vectors
+        take_partner = _lex_lt(arrs, parrs) ^ want_min
+        return tuple(jnp.where(take_partner, pa, a)
+                     for a, pa in zip(arrs, parrs))
+
+    if n <= 128:
+        # within one lane tile: unrolled, with static rotations (Mosaic
+        # does not lower a rotation by a traced amount of a partial tile)
+        for p in range(1, n.bit_length()):
+            for q in range(p):
+                arrs = stage(1 << p, (1 << (p - 1)) >> q, arrs)
+        return arrs
+
+    # wider rows run the stages in loops with a traced block size and
+    # stride: unrolled, the log^2 n stages make the kernel program (and
+    # its Mosaic compile time) grow as n log^2 n
+    def merge(p, arrs):
+        k = jnp.left_shift(1, p)
+        return jax.lax.fori_loop(
+            0, p, lambda q, a: stage(k, jnp.right_shift(k // 2, q), a), arrs)
+
+    return jax.lax.fori_loop(1, n.bit_length(), merge, arrs)
 
 
 def _segment_ranks(x, seg_id, seg_start, sort_impl):
@@ -217,17 +282,23 @@ def _segment_ranks(x, seg_id, seg_start, sort_impl):
             pos_p = jnp.concatenate(
                 [pos, width + jax.lax.broadcasted_iota(
                     jnp.int32, (bm, pad), 1)], axis=1)
-        _, neg_mag_s, pos_s = _bitonic_sort((seg_p, neg_p, pos_p))
-        neg_mag_s, pos_s = neg_mag_s[:, :width], pos_s[:, :width]
+        _, neg_mag_s, pos_p_sorted = _bitonic_sort((seg_p, neg_p, pos_p))
+        neg_mag_s = neg_mag_s[:, :width]
+        pos_s = pos_p_sorted[:, :width]
     else:
         raise ValueError(f"unknown sort_impl {sort_impl!r} "
                          f"(known: 'xla', 'bitonic')")
 
-    # invert the permutation: global sorted position of every column,
-    # then subtract the (static) segment start -> rank within segment
-    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    rank = jnp.zeros(x.shape, jnp.int32).at[rows, pos_s].set(
-        jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
+    # invert the permutation (global sorted position of every column) by
+    # sorting the sorted positions back into column order -- a second
+    # sort, not a scatter, which Mosaic does not lower -- then subtract
+    # the (static) segment start -> rank within segment
+    order = jax.lax.broadcasted_iota(jnp.int32, pos_s.shape, 1)
+    if sort_impl == "xla":
+        _, rank = jax.lax.sort((pos_s, order), dimension=1, num_keys=1)
+    else:
+        _, rank = _bitonic_sort((pos_p_sorted, _pad_cols(order, pad, 0)))
+        rank = rank[:, :width]
     rank = rank - seg_start
     # recover |x| in sorted order from the key bits (exact for f32/bf16)
     sorted_mag = jax.lax.bitcast_convert_type(
@@ -252,7 +323,7 @@ def _select_k(sorted_mag, mode, ratio, energy, m):
     registry compressor op-for-op so the traced k_i is bit-identical."""
     if mode == "topk":
         return _seg_k(ratio, m)            # static, same for every agent
-    cum = jnp.cumsum(jnp.square(sorted_mag), axis=-1)
+    cum = prefix_sum(jnp.square(sorted_mag), _lane_shift_right)
     total = jnp.maximum(cum[:, -1:], 1e-30)
     k = jnp.sum(cum < energy * total, axis=-1, keepdims=True) + 1
     return jnp.clip(k, _seg_k(ratio, m), m)
@@ -289,12 +360,14 @@ def _rank_select_kernel(x_ref, seg_ref, out_ref, *, segments, mode,
         raise ValueError(f"unknown sort_impl {sort_impl!r} "
                          f"(known: 'xla', 'bitonic')")
 
-    masks = []
+    # per-interval VALUES, not masks, are joined: Mosaic does not
+    # concatenate boolean vectors at offsets off the lane tile
+    outs = []
     rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
     for s0, s1, real in _column_intervals(segments, width):
         m = s1 - s0
         if not real:                       # padding: transmit nothing
-            masks.append(jnp.zeros((bm, m), jnp.bool_))
+            outs.append(jnp.zeros((bm, m), x.dtype))
             continue
         if sorted_neg_full is None and mode == "topk":
             # static k on CPU: top_k is a partial selection, cheaper
@@ -302,8 +375,9 @@ def _rank_select_kernel(x_ref, seg_ref, out_ref, *, segments, mode,
             # same discipline as the stable ranks)
             k = _seg_k(ratio, m)
             _, idx = jax.lax.top_k(jnp.abs(x[:, s0:s1]), k)
-            masks.append(jnp.zeros((bm, m), jnp.bool_).at[
-                rows[:, :k], idx].set(True))
+            mask = jnp.zeros((bm, m), jnp.bool_).at[
+                rows[:, :k], idx].set(True)
+            outs.append(jnp.where(mask, x[:, s0:s1], 0.0).astype(x.dtype))
             continue
         mag_key = _magnitude_key(x[:, s0:s1])
         if sorted_neg_full is not None:
@@ -325,10 +399,11 @@ def _rank_select_kernel(x_ref, seg_ref, out_ref, *, segments, mode,
         above = mag_key > kth
         tie = mag_key == kth
         n_above = jnp.sum(above, axis=-1, keepdims=True)
-        tie_prefix = jnp.cumsum(tie.astype(jnp.int32), axis=-1)
-        masks.append(above | (tie & (tie_prefix <= k - n_above)))
-    mask = masks[0] if len(masks) == 1 else jnp.concatenate(masks, axis=1)
-    out_ref[...] = jnp.where(mask, x, 0.0).astype(out_ref.dtype)
+        tie_prefix = prefix_sum(tie.astype(jnp.int32), _lane_shift_right)
+        mask = above | (tie & (tie_prefix <= k - n_above))
+        outs.append(jnp.where(mask, x[:, s0:s1], 0.0).astype(x.dtype))
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    out_ref[...] = out.astype(out_ref.dtype)
 
 
 def _segment_ranks_kernel(x_ref, seg_ref, start_ref, rank_ref, *,
@@ -336,6 +411,19 @@ def _segment_ranks_kernel(x_ref, seg_ref, start_ref, rank_ref, *,
     rank, _ = _segment_ranks(x_ref[...], seg_ref[...], start_ref[...],
                              sort_impl)
     rank_ref[...] = rank
+
+
+def _round_half_even(v):
+    """``jnp.round`` (ties to even) from ``floor``, in f32: Mosaic has no
+    bf16 ``roundeven``.  Exact for every input ``jnp.round`` sees here:
+    the widening cast, ``v - floor(v)`` and the halving are all exact,
+    so the result equals ``jnp.round(v)`` bit for bit."""
+    v = v.astype(jnp.float32)
+    r = jnp.floor(v)
+    frac = v - r
+    odd = r - 2.0 * jnp.floor(0.5 * r)
+    up = (frac > 0.5) | ((frac == 0.5) & (odd == 1.0))
+    return r + up.astype(jnp.float32)
 
 
 def _int8_kernel(x_ref, out_ref, *, segments):
@@ -352,7 +440,7 @@ def _int8_kernel(x_ref, out_ref, *, segments):
         sl = x[:, s0:s1]
         scale = jnp.max(jnp.abs(sl), axis=-1, keepdims=True) / 127.0
         scale = jnp.maximum(scale, 1e-12)
-        q = jnp.round(sl / scale).astype(jnp.int8)
+        q = _round_half_even(sl / scale).astype(jnp.int8)
         outs.append(q.astype(x.dtype) * scale)
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
     out_ref[...] = out.astype(out_ref.dtype)

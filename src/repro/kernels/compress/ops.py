@@ -22,8 +22,38 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ON_TPU
-from repro.kernels.compress.kernel import (BLOCK_AGENTS, int8_2d,
+from repro.kernels.compress.kernel import (BLOCK_AGENTS, _pow2_pad, int8_2d,
                                            rank_select_2d, segment_ranks_2d)
+
+# Scoped VMEM a Mosaic kernel may use by default on a TPU v5e.
+VMEM_LIMIT_BYTES = 16 * 2**20
+
+# VMEM bytes each element of a kernel's (rows, width) block keeps live,
+# keyed by compressor: input and output blocks (double-buffered) plus
+# in-kernel temporaries -- for the rank select (topk, adaptive_topk, and
+# the sort segment_ranks shares) the sort keys over the power-of-two
+# padded row.  Calibrated against the v5e compiler: the widest block
+# each allows compiles (tests/test_tpu_compile.py), and at the next
+# power of two the compiler runs out of VMEM.
+_VMEM_BYTES_PER_ELEMENT = {"int8": 32, "topk": 128, "adaptive_topk": 128}
+
+_SUBLANES = 8      # a block's rows occupy at least one (8, 128) tile
+
+
+def block_vmem_bytes(compressor, width):
+    """Estimated VMEM working set of one grid program of
+    ``compressor``'s kernel on a buffer ``width`` columns wide.  A block
+    holds ``min(8, N)`` whole rows, padded to the 8-row tile, so the
+    estimate does not depend on the agent count."""
+    cols = (-(-width // 128) * 128 if compressor == "int8"
+            else _pow2_pad(width)[0])
+    return _SUBLANES * cols * _VMEM_BYTES_PER_ELEMENT[compressor]
+
+
+def fits_vmem(compressor, width):
+    """Whether ``compressor``'s whole-row kernel block fits the chip's
+    fast memory at ``width`` columns (a packed model row never does)."""
+    return block_vmem_bytes(compressor, width) <= VMEM_LIMIT_BYTES
 
 
 def _resolve(x, segments, interpret, sort_impl):
@@ -78,15 +108,13 @@ def rank_select(x, *, segments=None, mode="topk", ratio=0.25,
 
 @partial(jax.jit, static_argnames=("segments", "interpret", "sort_impl",
                                    "block_agents"))
-def segment_ranks(x, *, segments=None, interpret=True, sort_impl=None,
+def segment_ranks(x, *, segments=None, interpret=None, sort_impl=None,
                   block_agents=BLOCK_AGENTS):
     """Stable descending-|x| rank of every entry within its segment.
 
-    An introspection/test surface: materializing ranks inverts the sort
-    permutation with a batched scatter, which has no Mosaic lowering --
-    so unlike the compressor ops this one defaults to ``interpret=True``
-    everywhere (the compressors themselves use the scatter-free counting
-    form and never need the rank array)."""
+    An introspection/test surface (the compressors use the counting
+    form and never need the rank array); the permutation is inverted by
+    a second sort, so it lowers wherever the compressors do."""
     segments, interpret, sort_impl = _resolve(x, segments, interpret,
                                               sort_impl)
     xp, n = _pad_rows(x, block_agents)

@@ -11,6 +11,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.compress.kernel import prefix_sum
+
 
 def _segments_of(x, segments):
     return (((0, x.shape[1]),) if segments is None
@@ -48,7 +50,7 @@ def rank_select_ref(x, segments=None, mode="topk", ratio=0.25,
             def adaptive_row(row):
                 e = jnp.square(jnp.abs(row))
                 desc = jnp.sort(e)[::-1]
-                cum = jnp.cumsum(desc)
+                cum = prefix_sum(desc)
                 total = jnp.maximum(cum[-1], 1e-30)
                 k = jnp.sum(cum < energy * total) + 1
                 k = jnp.clip(k, k_floor, m)

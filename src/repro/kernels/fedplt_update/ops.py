@@ -31,6 +31,15 @@ def fedplt_update(w, g, v, t=None, *, gamma: float, inv_rho: float,
     """Fused ``w - gamma (g + inv_rho (w - v)) [+ t]`` for one leaf."""
     if interpret is None:
         interpret = not ON_TPU
+    if w.ndim == 2 and w.shape[1] % BLOCK_N == 0 and (
+            w.shape[0] <= BLOCK_M or w.shape[0] % BLOCK_M == 0):
+        # already tileable, e.g. the packed (N, width) state buffer:
+        # flattening it would relayout the whole buffer on a TPU, where
+        # its agent axis sits in the minor tile
+        return fedplt_update_2d(
+            w, g.astype(w.dtype), v.astype(w.dtype),
+            None if t is None else t.astype(w.dtype), gamma=gamma,
+            inv_rho=inv_rho, interpret=interpret)
     w2, n = _pad_to_2d(w)
     g2, _ = _pad_to_2d(g.astype(w.dtype))
     v2, _ = _pad_to_2d(v.astype(w.dtype))

@@ -33,7 +33,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ON_TPU
@@ -176,7 +176,7 @@ def round_uplink_sharded(z, t=None, *, mesh, n_total, prox=None,
     spec = P(row_axis, col_axis)
     in_specs = (spec,) if t is None else (spec, spec)
     f = shard_map(_body, mesh=mesh, in_specs=in_specs,
-                  out_specs=(P(None, col_axis), spec), check_rep=False)
+                  out_specs=(P(None, col_axis), spec), check_vma=False)
     return f(z) if t is None else f(z, t)
 
 
@@ -201,5 +201,5 @@ def round_downlink_sharded(x, w, z, y, u, *, mesh, damping=1.0,
     f = shard_map(_body, mesh=mesh,
                   in_specs=(spec, spec, spec, P(None, col_axis),
                             P(row_axis)),
-                  out_specs=(spec, spec), check_rep=False)
+                  out_specs=(spec, spec), check_vma=False)
     return f(x, w, z, y, u.reshape(-1))

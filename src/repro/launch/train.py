@@ -26,11 +26,13 @@ from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.data.synthetic import make_batch_for
 from repro.fed import api
+from repro.launch.cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.optim import adamw, apply_updates, momentum, sgd
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--mode", default="fed", choices=["fed", "standard"])

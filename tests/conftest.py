@@ -1,99 +1,9 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here -- smoke tests and benches
 must see the single real CPU device; only dryrun.py forces 512.
-
-Also installs a minimal fallback shim for ``hypothesis`` when the real
-package is not available (the container images only guarantee jax +
-numpy + pytest): the property tests then run a fixed-seed sweep of
-random examples instead of being collection errors.  Install the real
-``hypothesis`` (the ``test`` extra in pyproject.toml) to get shrinking
-and the full example database.
 """
-
-import random
-import sys
-import types
 
 import jax
 import pytest
-
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    _MAX_EXAMPLES_CAP = 16  # keep interpret-mode kernel sweeps fast on CPU
-
-    class _Strategy:
-        """A draw function wrapper; only the strategies the suite uses."""
-
-        def __init__(self, draw):
-            self.draw = draw
-
-    def _floats(min_value=0.0, max_value=1.0, **_kw):
-        return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-    def _integers(min_value=0, max_value=100):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def _lists(elem, min_size=0, max_size=10, **_kw):
-        def draw(rng):
-            n = rng.randint(min_size, max_size)
-            return [elem.draw(rng) for _ in range(n)]
-
-        return _Strategy(draw)
-
-    def _sampled_from(seq):
-        seq = list(seq)
-        return _Strategy(lambda rng: seq[rng.randrange(len(seq))])
-
-    def _booleans():
-        return _Strategy(lambda rng: rng.random() < 0.5)
-
-    def _just(value):
-        return _Strategy(lambda rng: value)
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.floats = _floats
-    _st.integers = _integers
-    _st.lists = _lists
-    _st.sampled_from = _sampled_from
-    _st.booleans = _booleans
-    _st.just = _just
-
-    def _settings(max_examples=10, **_kw):
-        def deco(fn):
-            fn._stub_max_examples = max_examples
-            return fn
-
-        return deco
-
-    def _given(*strategies, **kw_strategies):
-        def deco(fn):
-            n = min(getattr(fn, "_stub_max_examples", 10),
-                    _MAX_EXAMPLES_CAP)
-
-            def runner():
-                rng = random.Random(0)  # deterministic across runs
-                for _ in range(n):
-                    args = [s.draw(rng) for s in strategies]
-                    kwargs = {k: s.draw(rng)
-                              for k, s in kw_strategies.items()}
-                    fn(*args, **kwargs)
-
-            # no functools.wraps: pytest would follow __wrapped__ and
-            # mistake the example parameters for fixtures
-            runner.__name__ = fn.__name__
-            runner.__doc__ = fn.__doc__
-            runner.__module__ = fn.__module__
-            return runner
-
-        return deco
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__stub__ = True
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 
 @pytest.fixture(autouse=True, scope="module")

@@ -239,7 +239,9 @@ def _packed_round_jaxpr(backend, comp):
     spec = FedSpec(
         n_agents=4, engine_backend=backend, state_layout="packed",
         gamma=0.1, participation=0.9,
-        compression=(CompressionSpec(name=comp, ratio=0.5)
+        # the per-segment XLA compressors: at this width "auto" would
+        # take the compress kernel, whose in-VMEM body concatenates
+        compression=(CompressionSpec(name=comp, ratio=0.5, backend="xla")
                      if comp != "none" else CompressionSpec()))
     ecfg = spec.round_config()
     solver = make_packed_local_solver(scfg, fgrad, spec.rho, 0.1, 1.0,
@@ -324,19 +326,25 @@ def _ccfg(name, backend="auto", ratio=0.5):
 
 
 def test_auto_backend_dispatch():
-    # explicit backends pass through untouched
+    # explicit backends pass through untouched where the block fits
     assert resolve_backend(_ccfg("topk", "pallas")) == "pallas"
-    assert resolve_backend(_ccfg("topk", "xla")) == "xla"
-    # adaptive_topk: pallas always (one fused pass beats xla's two)
-    assert resolve_backend(_ccfg("adaptive_topk")) == "pallas"
-    # topk: xla always (lax.top_k wins at every measured size)
-    assert resolve_backend(_ccfg("topk")) == "xla"
-    # int8: pallas only pays off on wide buffers
-    assert resolve_backend(_ccfg("int8"), m_total=1 << 15) == "pallas"
-    assert resolve_backend(_ccfg("int8"), m_total=1 << 10) == "xla"
+    assert resolve_backend(_ccfg("topk", "pallas"), m_total=1 << 14) \
+        == "pallas"
+    assert resolve_backend(_ccfg("topk", "xla"), m_total=1 << 30) == "xla"
+    # auto: the kernel exactly where its whole-row block fits VMEM
+    for name in ("topk", "adaptive_topk"):
+        assert resolve_backend(_ccfg(name), m_total=1 << 14) == "pallas"
+        assert resolve_backend(_ccfg(name), m_total=(1 << 14) + 1) == "xla"
+    assert resolve_backend(_ccfg("int8"), m_total=1 << 16) == "pallas"
+    assert resolve_backend(_ccfg("int8"), m_total=(1 << 16) + 128) == "xla"
+    assert resolve_backend(_ccfg("int8"), m_total=355_000_000) == "xla"
     assert resolve_backend(_ccfg("int8")) == "xla"  # unknown width
     # compressors without a kernel never route to pallas
     assert resolve_backend(_ccfg("none")) == "xla"
+    # an explicit kernel request beyond the block limit is refused
+    for name in ("topk", "adaptive_topk", "int8"):
+        with pytest.raises(ValueError, match="VMEM"):
+            resolve_backend(_ccfg(name, "pallas"), m_total=1 << 20)
 
 
 def test_auto_is_the_default_backend():

@@ -159,17 +159,11 @@ def test_hlo_analyzer_collectives():
     mesh = jax.make_mesh((1,), ("d",))
     from jax.sharding import PartitionSpec as P
 
-    # jax.shard_map only exists from 0.5; fall back to the experimental
-    # home so the test runs on the pinned 0.4.x too
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-
     def f(x):
         return jax.lax.psum(x, "d")
 
     fn = jax.jit(
-        shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P()))
+        jax.shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P()))
     txt = fn.lower(jnp.ones((8, 128))).compile().as_text()
     c = analyze_text(txt)
     # single-device all-reduce may be optimized away; just assert parse ok
