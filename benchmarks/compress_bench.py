@@ -9,27 +9,26 @@ Two parts (this harness absorbed the PR-1-era ``compression_bench``):
   relative uplink bytes the compressor buys (keep * value bits vs 32-bit
   exact exchange).
 
-* **Perf**: ``compress_increment`` wall time, backend x compressor x
-  shape -- per-leaf XLA registry path vs the packed
-  :mod:`repro.kernels.compress` Pallas path (interpret mode on this CPU
-  container), including the engine-scale ragged pytree (the reduced
-  gemma2-2b leaf layout ``engine_bench`` rounds flatten).  The
-  ``speedup`` column is XLA time / Pallas time for the same case.
+* **Kernels**: for each compressor with a fused
+  :mod:`repro.kernels.compress` kernel and each shape (including the
+  engine-scale ragged pytree of the reduced gemma2-2b leaf layout),
+  whether the kernel's whole-row block fits (``fits_vmem``, the rule of
+  the ``auto`` backend) and, where it does, whether its output is
+  bit-identical to the per-leaf XLA registry path.
 
+No wall time: device times come from the chip benchmark under
+``bench/``; CPU and interpret-mode times are not evidence of speed.
 ``run`` returns ``(rows, payload)``: CSV rows plus the JSON-able dict
 ``benchmarks.run --json`` writes (committed baseline:
-``BENCH_compress.json``), so future PRs can regress against per-case
-wall times and speedups.
+``BENCH_compress.json``).
 
 Rows::
 
   compress_bench,conv:<name>,<rounds-to-threshold>,<final criterion>,
-      keep=..;uplink=..;ms=..
-  compress_bench,perf:<case>:<name>:<backend>,<ms/call>,<speedup vs
-      xla>,N=..;m=..;leaves=..
+      keep=..;uplink=..
+  compress_bench,kernel:<case>:<name>,kernel=<0|1>,bitwise=<0|1|->,
+      N=..;m=..;leaves=..
 """
-
-import time
 
 import jax
 import jax.numpy as jnp
@@ -53,8 +52,8 @@ _GEMMA2R_LEAVES = (131072, 256, 65536, 65536, 65536, 65536, 256, 256,
                    262144, 131072, 65536, 65536, 65536, 65536, 256, 256,
                    262144, 131072)
 
-# perf sweep: (case name, n_agents, per-leaf widths)
-_PERF_CASES = (
+# kernel sweep: (case name, n_agents, per-leaf widths)
+_KERNEL_CASES = (
     ("dense100x256", 100, (256,)),
     ("wide8x65536", 8, (65536,)),
     ("engine_gemma2r", 2, _GEMMA2R_LEAVES),
@@ -79,10 +78,8 @@ def _convergence(quick):
         comp = CompressionSpec(name=name, ratio=0.25, energy=0.9)
         trainer = build_trainer(
             prob, FedSpec(rho=1.0, n_epochs=5, compression=comp))
-        t0 = time.perf_counter()
         _, crit = trainer.run(jax.random.PRNGKey(0), rounds)
-        crit = np.asarray(crit)          # blocks on the scan
-        ms = (time.perf_counter() - t0) / rounds * 1e3
+        crit = np.asarray(crit)
         k = hitting_round(crit)
         rc = trainer.spec.round_config()
         kept = float(jnp.mean(get_compressor(name)(probe, rc) != 0.0))
@@ -94,66 +91,56 @@ def _convergence(quick):
         up_s = f"{uplink:.2f}" if uplink is not None else "-"
         rows.append(f"compress_bench,conv:{name},{k if k else '-'},"
                     f"{crit[-1]:.3e},keep={kept:.2f};"
-                    f"uplink={up_s};ms={ms:.2f}")
+                    f"uplink={up_s}")
         payload.append(dict(kind="convergence", compressor=name,
                             rounds_to_threshold=k,
                             final_criterion=float(crit[-1]),
-                            keep_fraction=kept, rel_uplink=uplink,
-                            ms_per_round=ms))
+                            keep_fraction=kept, rel_uplink=uplink))
     return rows, payload
 
 
-def _time_compress(tree, cfg, iters):
-    f = jax.jit(lambda t: compress_increment(t, cfg))
-    out = f(tree)
-    jax.block_until_ready(out)           # compile + warm-up
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = f(tree)
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / iters * 1e3
-
-
-def _perf(quick):
+def _kernels():
     rows, payload = [], []
-    iters = 3 if quick else 10
     key = jax.random.PRNGKey(0)
-    for case, n_agents, widths in _PERF_CASES:
+    for case, n_agents, widths in _KERNEL_CASES:
         tree = {f"l{i}": jax.random.normal(jax.random.fold_in(key, i),
                                            (n_agents, w))
                 for i, w in enumerate(widths)}
         m_total = int(sum(widths))
         for name in sorted(PALLAS_COMPRESSORS):
-            if not fits_vmem(name, m_total):
-                continue       # no kernel at this width: nothing to compare
-            ms = {}
-            for backend in ("xla", "pallas"):
-                cfg = RoundConfig(
-                    n_agents=n_agents, compression=name,
-                    compress_ratio=0.25, compress_energy=0.9,
-                    compress_backend=backend)
-                ms[backend] = _time_compress(tree, cfg, iters)
-            speedup = ms["xla"] / ms["pallas"]
-            for backend in ("xla", "pallas"):
-                rel = speedup if backend == "pallas" else 1.0
-                rows.append(
-                    f"compress_bench,perf:{case}:{name}:{backend},"
-                    f"{ms[backend]:.2f},{rel:.2f}x,"
-                    f"N={n_agents};m={m_total};leaves={len(widths)}")
-                payload.append(dict(
-                    kind="perf", case=case, compressor=name,
-                    backend=backend, n_agents=n_agents,
-                    m_total=m_total, n_leaves=len(widths),
-                    ms_per_call=ms[backend], speedup_vs_xla=rel))
+            kernel = fits_vmem(name, m_total)
+            bitwise = None
+            if kernel:
+                out = []
+                for backend in ("xla", "pallas"):
+                    cfg = RoundConfig(
+                        n_agents=n_agents, compression=name,
+                        compress_ratio=0.25, compress_energy=0.9,
+                        compress_backend=backend)
+                    out.append(jax.jit(
+                        lambda t, cfg=cfg: compress_increment(t, cfg))(tree))
+                bitwise = all(
+                    np.array_equal(a, b) for a, b in zip(
+                        jax.tree_util.tree_leaves(out[0]),
+                        jax.tree_util.tree_leaves(out[1])))
+            bit_s = "-" if bitwise is None else int(bitwise)
+            rows.append(
+                f"compress_bench,kernel:{case}:{name},kernel={int(kernel)},"
+                f"bitwise={bit_s},"
+                f"N={n_agents};m={m_total};leaves={len(widths)}")
+            payload.append(dict(
+                kind="kernel", case=case, compressor=name,
+                n_agents=n_agents, m_total=m_total, n_leaves=len(widths),
+                kernel=bool(kernel), bitwise_equal=bitwise))
     return rows, payload
 
 
 def run(quick=True):
     conv_rows, conv_payload = _convergence(quick)
-    perf_rows, perf_payload = _perf(quick)
-    payload = {"cases": conv_payload + perf_payload,
+    kernel_rows, kernel_payload = _kernels()
+    payload = {"cases": conv_payload + kernel_payload,
                "quick": bool(quick)}
-    return conv_rows + perf_rows, payload
+    return conv_rows + kernel_rows, payload
 
 
 if __name__ == "__main__":

@@ -40,7 +40,7 @@ import jax
 
 from repro.core import prox as prox_lib
 from repro.core.solvers import SolverConfig
-from repro.fed import engine
+from repro.fed import engine, telemetry
 from repro.fed.compress import (COMPRESS_BACKENDS, available_compressors,
                                 get_compressor)
 from repro.fed.robust import available_aggregators, validate_aggregator
@@ -901,21 +901,25 @@ class DenseTrainer(FedTrainer):
                            mesh=self._resolved.build_mesh())
 
     def init(self, key: jax.Array):
-        return self.algo.init(key)
+        with telemetry.span("fedplt.init"):
+            return self.algo.init(key)
 
     def step(self, state):
         """One Fed-PLT round (jitted)."""
-        return self.algo.round(state)
+        with telemetry.span("fedplt.step", step=True):
+            return self.algo.round(state)
 
     def run(self, key: jax.Array, n_rounds: int):
         """Run from a fresh init; returns (state, criterion_history)."""
-        return self.algo.run(key, n_rounds)
+        with telemetry.span("fedplt.run", rounds=n_rounds):
+            return self.algo.run(key, n_rounds)
 
     def run_recorded(self, key: jax.Array, n_rounds: int):
         """:meth:`run` that also returns the realized ``(n_rounds, N)``
         arrival schedule (feed it to :meth:`effective_privacy_report`
         or :meth:`replay`)."""
-        return self.algo.run_recorded(key, n_rounds)
+        with telemetry.span("fedplt.run", rounds=n_rounds):
+            return self.algo.run_recorded(key, n_rounds)
 
     def replay(self, key: jax.Array, schedule):
         """Re-run a recorded arrival schedule through the in-jit async
@@ -1001,10 +1005,11 @@ class ModelTrainer(FedTrainer):
         return sharding.shardings(self.mesh, specs)
 
     def init(self, key: jax.Array):
-        state = self._runtime.init_state(self.model, key, self.spec)
-        if self.mesh is None:
-            return state
-        return jax.device_put(state, self._state_shardings())
+        with telemetry.span("fedplt.init"):
+            state = self._runtime.init_state(self.model, key, self.spec)
+            if self.mesh is None:
+                return state
+            return jax.device_put(state, self._state_shardings())
 
     def step(self, state, batch, key: jax.Array, arrival=None,
              corrupt=None, live=None):
@@ -1014,7 +1019,15 @@ class ModelTrainer(FedTrainer):
         ``corrupt`` / ``live`` are the broker's fault overrides (see
         :mod:`repro.fed.broker`): per-agent corruption multipliers and
         the survivor mask after evictions."""
-        return self._step(state, batch, key, arrival, corrupt, live)
+        with telemetry.span("fedplt.step", step=True):
+            return self._step(state, batch, key, arrival, corrupt, live)
+
+    def lower(self, state, batch, key: jax.Array, arrival=None,
+              corrupt=None, live=None) -> jax.stages.Lowered:
+        """The round :meth:`step` runs, lowered for these arguments
+        (arrays or ``jax.ShapeDtypeStruct``); ``.compile().as_text()``
+        is the HLO that :func:`repro.fed.telemetry.op_scopes` reads."""
+        return self._step.lower(state, batch, key, arrival, corrupt, live)
 
     def run(self, key: jax.Array, n_rounds: int, batches):
         """Run from a fresh init.  ``batches`` is either a callable
@@ -1024,19 +1037,22 @@ class ModelTrainer(FedTrainer):
         row) as numpy arrays."""
         import numpy as np
 
-        state = self.init(key)
-        if callable(batches):
-            get = batches
-        else:
-            it = iter(batches)
-            get = lambda i: next(it)  # noqa: E731
-        history = []
-        for i in range(n_rounds):
-            state, m = self.step(state, get(i), jax.random.fold_in(key, i))
-            history.append({
-                k: float(v) if getattr(v, "ndim", 0) == 0 else np.asarray(v)
-                for k, v in m.items()})
-        return state, history
+        with telemetry.span("fedplt.run", rounds=n_rounds):
+            state = self.init(key)
+            if callable(batches):
+                get = batches
+            else:
+                it = iter(batches)
+                get = lambda i: next(it)  # noqa: E731
+            history = []
+            for i in range(n_rounds):
+                state, m = self.step(state, get(i),
+                                     jax.random.fold_in(key, i))
+                history.append({
+                    k: float(v) if getattr(v, "ndim", 0) == 0
+                    else np.asarray(v)
+                    for k, v in m.items()})
+            return state, history
 
     def consensus(self, state):
         return self._runtime.consensus_model(state, meta=self.packed_meta)
@@ -1063,12 +1079,13 @@ def build_trainer(problem_or_model, spec: Any) -> FedTrainer:
     may be a :class:`FedSpec` or any legacy config with ``.to_spec()``.
     """
     spec = as_spec(spec)
-    if hasattr(problem_or_model, "local_loss") and \
-            hasattr(problem_or_model, "n_agents"):
-        return DenseTrainer(problem_or_model, spec)
-    if hasattr(problem_or_model, "loss_fn") and \
-            hasattr(problem_or_model, "init"):
-        return ModelTrainer(problem_or_model, spec)
+    with telemetry.span("fedplt.build"):
+        if hasattr(problem_or_model, "local_loss") and \
+                hasattr(problem_or_model, "n_agents"):
+            return DenseTrainer(problem_or_model, spec)
+        if hasattr(problem_or_model, "loss_fn") and \
+                hasattr(problem_or_model, "init"):
+            return ModelTrainer(problem_or_model, spec)
     raise TypeError(
         f"cannot build a trainer for {type(problem_or_model).__name__}: "
         f"expected a dense problem (local_loss/n_agents) or a model "

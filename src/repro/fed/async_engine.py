@@ -78,7 +78,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fed import compress as compress_lib
 from repro.fed import engine
 from repro.fed.engine import (ASYNC_MODES, ProxH,  # noqa: F401  (re-export)
                               RoundConfig, SolverAssignment,
@@ -261,13 +260,7 @@ def async_round_step(cfg: RoundConfig, x: Any, z: Any, t: Any,
                                live)
 
     # -- compressed uplink: only arrived increments are transmitted -----
-    if cfg.compressed:
-        q = engine.compress_increment(
-            tree_map(jnp.subtract, z_new, t), cfg)
-        t_new = tree_map(
-            lambda tl, ql: tl + _vec(u.astype(ql.dtype), ql) * ql, t, q)
-    else:
-        t_new = z_new
+    t_new = engine.transmit(cfg, z_new, t, u) if cfg.compressed else z_new
 
     return AsyncRoundResult(x=x_new, z=z_new, t=t_new, y=y,
                             y_tag=y_tag_new, staleness=s_new,
@@ -332,11 +325,8 @@ def packed_async_round_step(cfg: RoundConfig, meta, x: jnp.ndarray,
     s_new = _advance_staleness(staleness, u, cfg.staleness.max_staleness,
                                live)
 
-    if cfg.compressed:
-        q = compress_lib.compress_increment_packed(z_new - t, meta, cfg)
-        t_new = t + u.astype(q.dtype).reshape(-1, 1) * q
-    else:
-        t_new = z_new
+    t_new = (engine.transmit(cfg, z_new, t, u, meta) if cfg.compressed
+             else z_new)
 
     return AsyncRoundResult(x=x_new, z=z_new, t=t_new, y=y,
                             y_tag=y_tag_new, staleness=s_new,

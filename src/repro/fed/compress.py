@@ -48,6 +48,7 @@ from typing import Any, Callable, Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.fed import telemetry
 from repro.kernels.compress.kernel import prefix_sum
 
 # (dz_rows (N, m), round_cfg) -> transmitted rows (N, m)
@@ -317,6 +318,7 @@ def _tree_m_total(leaves) -> int:
     return total
 
 
+@telemetry.scope("fedplt.compress")
 def compress_increment(dz: Any, cfg) -> Any:
     """Apply the configured compressor to a stacked increment pytree
     (top-k / int8 scales are per agent per leaf, which is what an actual
@@ -345,6 +347,7 @@ def compress_increment(dz: Any, cfg) -> Any:
     return jax.tree_util.tree_map(leaf, dz)
 
 
+@telemetry.scope("fedplt.compress")
 def compress_increment_packed(dz_buf: jnp.ndarray, meta: PackedMeta,
                               cfg) -> jnp.ndarray:
     """The configured compressor on a RESIDENT packed ``(N, width)``

@@ -128,6 +128,17 @@ a 1-device mesh remains bitwise identical to the unsharded engine
 (the gather of one shard is the identity).  Tree-layout robust rounds
 under a mesh compute the aggregate globally (GSPMD inserts the
 collectives) before the sharded edges run.
+
+NAMED SCOPES: each phase of the round carries a ``jax.named_scope``
+(:func:`repro.fed.telemetry.scope`) on the shared function every path
+calls -- ``fedplt.uplink`` (:func:`coordinator_edge`,
+:func:`coordinator_edge_packed`), ``fedplt.aggregate``
+(:func:`robust_seen`), ``fedplt.local_solver`` (:func:`run_solvers`),
+``fedplt.downlink`` (:func:`agent_edge`, :func:`agent_edge_packed`,
+:func:`participation_mask`) and ``fedplt.compress`` (:func:`transmit`).
+A scope names ops in their metadata only, so trajectories keep their
+bits; :func:`repro.fed.telemetry.op_scopes` maps a compiled round's ops
+back to these phases.
 """
 
 from __future__ import annotations
@@ -143,6 +154,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.fed import compress as compress_lib
+from repro.fed import telemetry
 from repro.fed.compress import compress_increment, get_compressor
 
 tree_map = jax.tree_util.tree_map
@@ -459,6 +471,7 @@ def reflect(y: Any, z: Any) -> Any:
     return tree_map(lambda yl, zl: 2.0 * yl[None] - zl, y, z)
 
 
+@telemetry.scope("fedplt.downlink")
 def participation_mask(key: jax.Array, cfg: RoundConfig) -> jnp.ndarray:
     """One Bernoulli(p_i) draw per agent, as a float (N,) vector.
 
@@ -607,6 +620,7 @@ def survivor_mean_input(cfg: RoundConfig, z_seen: Any, live) -> Any:
         z_seen)
 
 
+@telemetry.scope("fedplt.aggregate")
 def robust_seen(cfg: RoundConfig, z_seen: Any, live, meta=None,
                 mesh=None) -> Any:
     """The uplink's aggregation input transform -- THE one place the
@@ -846,6 +860,7 @@ def _tree_downlink_sharded(cfg: RoundConfig, u: jnp.ndarray, w: Any,
     return f(u.reshape(-1), w, x, z, y)
 
 
+@telemetry.scope("fedplt.uplink")
 def coordinator_edge(cfg: RoundConfig, z: Any, z_seen: Any,
                      prox_h: ProxH = None, mesh=None) -> Tuple[Any, Any]:
     """The round's uplink edge: ``y = prox_{rho h/N}(mean_i z_seen_i)``
@@ -884,6 +899,7 @@ def coordinator_edge(cfg: RoundConfig, z: Any, z_seen: Any,
     return y, reflect(y, z)
 
 
+@telemetry.scope("fedplt.downlink")
 def agent_edge(cfg: RoundConfig, u: jnp.ndarray, w: Any, x: Any, z: Any,
                y: Any, z_seen: Any = None,
                prox_h: ProxH = None, mesh=None) -> Tuple[Any, Any]:
@@ -942,6 +958,7 @@ def agent_edge(cfg: RoundConfig, u: jnp.ndarray, w: Any, x: Any, z: Any,
 # module docstring)
 # ---------------------------------------------------------------------------
 
+@telemetry.scope("fedplt.uplink")
 def coordinator_edge_packed(cfg: RoundConfig, z: jnp.ndarray,
                             z_seen: jnp.ndarray, meta,
                             prox_h: ProxH = None, mesh=None) \
@@ -988,6 +1005,7 @@ def coordinator_edge_packed(cfg: RoundConfig, z: jnp.ndarray,
     return y, 2.0 * y - z
 
 
+@telemetry.scope("fedplt.downlink")
 def agent_edge_packed(cfg: RoundConfig, u: jnp.ndarray, w: jnp.ndarray,
                       x: jnp.ndarray, z: jnp.ndarray, y: jnp.ndarray,
                       z_seen: jnp.ndarray,
@@ -1056,11 +1074,7 @@ def packed_round_step(cfg: RoundConfig, meta, x: jnp.ndarray,
     x_new, z_new = agent_edge_packed(cfg, u, w, x, z, y, z_seen, prox_h,
                                      mesh)
 
-    if cfg.compressed:
-        q = compress_lib.compress_increment_packed(z_new - t, meta, cfg)
-        t_new = t + u.astype(q.dtype).reshape(-1, 1) * q
-    else:
-        t_new = z_new
+    t_new = transmit(cfg, z_new, t, u, meta) if cfg.compressed else z_new
 
     return RoundResult(x=x_new, z=z_new, t=t_new, y=y, next_key=key,
                        u=u, aux=aux)
@@ -1098,6 +1112,7 @@ def _slice_agents(tree: Any, start: int, stop: int) -> Any:
     return tree_map(lambda l: l[start:stop], tree)
 
 
+@telemetry.scope("fedplt.local_solver")
 def run_solvers(local_solver: SolverAssignment, x: Any, v: Any,
                 key: jax.Array, n_agents: int) -> Tuple[Any, Any]:
     """Dispatch the round's solver assignment on the reflected states.
@@ -1142,6 +1157,30 @@ def run_solvers(local_solver: SolverAssignment, x: Any, v: Any,
 # Compressed z-exchange: the compressor itself lives in the
 # repro.fed.compress registry; `compress_increment` is re-exported above
 # so front ends keep one import site.
+# ---------------------------------------------------------------------------
+
+@telemetry.scope("fedplt.compress")
+def transmit(cfg: RoundConfig, z_new: Any, t: Any, u: jnp.ndarray,
+             meta=None) -> Any:
+    """The compressed uplink: agents send ``q = C(z_new - t)`` and the
+    coordinator's copy advances by what was sent, ``t + u * q``.  ``meta``
+    marks the packed form (resident ``(N, width)`` buffers).
+
+    Arithmetic (``u*q``) masking, not ``jnp.where``: an inactive agent's
+    increment is computed from its own finite old state, so there is no
+    NaN hazard here, and the historical ``t + u*q`` lets XLA contract the
+    int8 dequant-multiply + add into one FMA -- keeping compressed
+    trajectories bit-identical to pre-refactor."""
+    if meta is None:
+        q = compress_increment(tree_map(jnp.subtract, z_new, t), cfg)
+    else:
+        q = compress_lib.compress_increment_packed(z_new - t, meta, cfg)
+    return tree_map(
+        lambda tl, ql: tl + u.astype(ql.dtype).reshape(
+            (-1,) + (1,) * (ql.ndim - 1)) * ql,
+        t, q)
+
+
 # ---------------------------------------------------------------------------
 # One round
 # ---------------------------------------------------------------------------
@@ -1189,19 +1228,7 @@ def round_step(cfg: RoundConfig, x: Any, z: Any, t: Any, key: jax.Array,
     x_new, z_new = agent_edge(cfg, u, w, x, z, y, z_seen, prox_h, mesh)
 
     # -- compressed uplink: t advances by the transmitted increment ------
-    if cfg.compressed:
-        q = compress_increment(tree_map(jnp.subtract, z_new, t), cfg)
-        # arithmetic (u*q) masking, not jnp.where: an inactive agent's
-        # increment is computed from its own finite old state so there is
-        # no NaN hazard here, and the historical `t + u*q` lets XLA
-        # contract the int8 dequant-multiply + add into one FMA --
-        # keeping compressed trajectories bit-identical to pre-refactor
-        t_new = tree_map(
-            lambda tl, ql: tl + u.astype(ql.dtype).reshape(
-                (-1,) + (1,) * (ql.ndim - 1)) * ql,
-            t, q)
-    else:
-        t_new = z_new
+    t_new = transmit(cfg, z_new, t, u) if cfg.compressed else z_new
 
     return RoundResult(x=x_new, z=z_new, t=t_new, y=y, next_key=key, u=u,
                        aux=aux)

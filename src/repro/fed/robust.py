@@ -85,6 +85,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.fed import compress as compress_lib
+from repro.fed import telemetry
 from repro.kernels.robust_agg.ref import robust_aggregate_ref
 
 tree_map = jax.tree_util.tree_map
@@ -256,6 +257,7 @@ def _segment_colmask(meta) -> Optional[np.ndarray]:
 # Engine entry points: the z_seen input transforms
 # ---------------------------------------------------------------------------
 
+@telemetry.scope("fedplt.aggregate")
 def robust_seen_packed(z_seen: jnp.ndarray, live, *, name: str,
                        param: float, meta, backend: str,
                        mesh=None, col_axis: Optional[str] = None
@@ -295,6 +297,7 @@ def robust_seen_packed(z_seen: jnp.ndarray, live, *, name: str,
     return f(z_seen, lv, jnp.asarray(cmask))
 
 
+@telemetry.scope("fedplt.aggregate")
 def robust_seen_tree(z_seen, live, *, name: str, param: float,
                      backend: str, mesh=None):
     """Robust ``z_seen`` transform on agent-stacked pytrees: pack the

@@ -292,18 +292,20 @@ def test_mixed_dtype_tree_falls_back_per_leaf():
 
 
 def test_compress_bench_perf_payload(monkeypatch):
-    """The --json emitter's per-case payload (wall time, speedup,
-    shapes) stays machine-readable: run the perf sweep on one tiny case
-    and check the committed-baseline schema."""
+    """The --json emitter's per-case payload stays machine-readable and
+    structural: run the kernel sweep on one tiny case and check the
+    committed-baseline schema -- shapes, whether a kernel serves the
+    width, and bit-identity with the XLA path; no wall time."""
     from benchmarks import compress_bench as cb
 
     # the engine-scale case the acceptance tracks is in the real sweep
-    assert "engine_gemma2r" in {c[0] for c in cb._PERF_CASES}
-    monkeypatch.setattr(cb, "_PERF_CASES", (("tiny", 2, (64, 30)),))
-    rows, payload = cb._perf(quick=True)
-    assert rows and len(payload) == 2 * len(sorted(PALLAS_COMPRESSORS))
-    assert {p["backend"] for p in payload} == {"xla", "pallas"}
+    assert "engine_gemma2r" in {c[0] for c in cb._KERNEL_CASES}
+    monkeypatch.setattr(cb, "_KERNEL_CASES", (("tiny", 2, (64, 30)),))
+    rows, payload = cb._kernels()
+    assert len(rows) == len(payload) == len(PALLAS_COMPRESSORS)
+    assert {p["compressor"] for p in payload} == set(PALLAS_COMPRESSORS)
     for p in payload:
-        assert p["kind"] == "perf" and p["case"] == "tiny"
+        assert p["kind"] == "kernel" and p["case"] == "tiny"
         assert p["m_total"] == 94 and p["n_leaves"] == 2
-        assert p["ms_per_call"] > 0.0 and p["speedup_vs_xla"] > 0.0
+        assert p["kernel"] is True and p["bitwise_equal"] is True
+        assert not any(k.startswith("ms_") or "speedup" in k for k in p)
