@@ -13,6 +13,7 @@ criterion.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -122,6 +123,33 @@ class FedPLTConfig:
             aggregator_param=self.aggregator_param)
 
 
+def _jit_data_as_args(fn, static_argnums=()):
+    """``jax.jit(fn, static_argnums=...)`` whose executable takes the
+    arrays ``fn`` closes over (the problem's data, the curvature moduli)
+    as arguments, as an eager ``lax.scan`` does.  Embedded as constants,
+    XLA folds them into the program: on the chip that moved the solve's
+    last bits and lengthened set-up by about a third.  One executable per
+    static value and argument shape; it caches programs, never results."""
+    compiled = {}
+
+    def call(*args):
+        dynamic = [a for i, a in enumerate(args) if i not in static_argnums]
+        flat, tree = jax.tree_util.tree_flatten(dynamic)
+        sig = (tuple(args[i] for i in static_argnums), tree,
+               tuple(map(jax.typeof, flat)))
+        if sig not in compiled:
+            closed, out = jax.make_jaxpr(fn, static_argnums=static_argnums,
+                                         return_shape=True)(*args)
+            run = jax.jit(functools.partial(jax.core.eval_jaxpr,
+                                            closed.jaxpr))
+            compiled[sig] = (run, closed.consts,
+                             jax.tree_util.tree_structure(out))
+        run, consts, out_tree = compiled[sig]
+        return jax.tree_util.tree_unflatten(out_tree, run(consts, *flat))
+
+    return call
+
+
 class FedPLT:
     """Paper-faithful Fed-PLT on a vectorized federated problem.
 
@@ -211,6 +239,11 @@ class FedPLT:
             self._solvers = tuple(self._solvers)
         self._round = jax.jit(self._round_impl)
         self._round_arrival = jax.jit(self._round_core)
+        # the whole solve (fresh init, scan over rounds, criterion) as one
+        # executable per length: an eager scan traces a new body on every
+        # call, so it misses the dispatch cache and compiles each time
+        self._solve = _jit_data_as_args(self._solve_impl, static_argnums=(1,))
+        self._replay = _jit_data_as_args(self._replay_impl)
 
     # ------------------------------------------------------------------
     def init(self, key: jax.Array) -> FedPLTState:
@@ -218,7 +251,11 @@ class FedPLT:
         k_init, k_state = jax.random.split(key)
         if self.cfg.dp_init and self.cfg.solver.tau > 0 and self.mu > 0:
             std = jnp.sqrt(2.0 * self.cfg.solver.tau ** 2 / self.mu)
-            x0 = std * jax.random.normal(k_init, (N, n))
+            # the barrier keeps XLA from folding std into the draw's own
+            # constants when init is compiled, so x0 has the same bits
+            # compiled as op by op
+            x0 = std * jax.lax.optimization_barrier(
+                jax.random.normal(k_init, (N, n)))
         else:
             x0 = jnp.zeros((N, n))
         # t (the coordinator's copy) is only materialized when the
@@ -380,13 +417,14 @@ class FedPLT:
         arrival schedule (the stacked per-round masks -- feed it to
         :func:`repro.fed.api.effective_privacy_report` or replay it with
         :meth:`replay`)."""
-        state = self.init(key)
+        return self._solve(key, n_rounds)
 
+    def _solve_impl(self, key, n_rounds):
         def body(s, _):
             s, u = self._round_core(s)
             return s, (self.problem.criterion(s.x), u)
 
-        state, (crit, sched) = jax.lax.scan(body, state, None,
+        state, (crit, sched) = jax.lax.scan(body, self.init(key), None,
                                             length=n_rounds)
         return state, crit, sched
 
@@ -396,15 +434,14 @@ class FedPLT:
         bit-identical to the run that recorded it (same init key)."""
         if not self._ecfg.staleness.enabled:
             raise ValueError("replay requires async_mode='stale'")
-        schedule = jnp.asarray(schedule, jnp.float32)
-        state = self.init(key)
+        return self._replay(key, jnp.asarray(schedule, jnp.float32))
 
+    def _replay_impl(self, key, schedule):
         def body(s, row):
             s, _ = self._round_core(s, row)
             return s, self.problem.criterion(s.x)
 
-        state, crit = jax.lax.scan(body, state, schedule)
-        return state, crit
+        return jax.lax.scan(body, self.init(key), schedule)
 
     # convenience -------------------------------------------------------
     def x_bar(self, state: FedPLTState) -> jnp.ndarray:

@@ -139,3 +139,71 @@ def test_dp_init_draws_random_x0(logreg):
     algo = FedPLT(logreg, cfg)
     st = algo.init(jax.random.PRNGKey(0))
     assert float(jnp.std(st.x)) > 0.01
+
+
+# ---------------------------------------------------------------------------
+# the compiled solve == the eager scan it replaced, bit for bit
+# ---------------------------------------------------------------------------
+
+GD = SolverConfig(name="gd", n_epochs=3, step_size=0.05)
+SOLVE_CASES = {
+    "gd": dict(cfg=dict(participation=0.6)),
+    "dp-init": dict(cfg=dict(dp_init=True, solver=SolverConfig(
+        name="noisy_gd", n_epochs=3, step_size=0.05, tau=0.05))),
+    "solver-groups": dict(groups=((3, GD), (5, SolverConfig(
+        name="agd", n_epochs=2)))),
+    "topk": dict(cfg=dict(compression="topk", compress_ratio=0.4,
+                          damping=0.5)),
+    "packed": dict(cfg=dict(state_layout="packed", participation=0.6)),
+    "stale": dict(cfg=dict(async_mode="stale", max_staleness=2,
+                           participation=0.5, damping=0.7)),
+}
+
+
+def _eager_solve(algo, key, n_rounds, schedule=None):
+    """The solve as an eager ``lax.scan`` over ``_round_core`` from an
+    op-by-op ``init``: ``(state, crit, sched)``, or ``(state, crit)``
+    when a recorded ``schedule`` is replayed."""
+    state = algo.init(key)
+    if schedule is None:
+        def body(s, _):
+            s, u = algo._round_core(s)
+            return s, (algo.problem.criterion(s.x), u)
+
+        state, (crit, sched) = jax.lax.scan(body, state, None,
+                                            length=n_rounds)
+        return state, crit, sched
+
+    def body(s, row):
+        s, _ = algo._round_core(s, row)
+        return s, algo.problem.criterion(s.x)
+
+    return jax.lax.scan(body, state, jnp.asarray(schedule, jnp.float32))
+
+
+def _assert_same_bits(got, want):
+    got, want = jax.tree_util.tree_flatten(got), jax.tree_util.tree_flatten(
+        want)
+    assert got[1] == want[1]
+    for a, b in zip(got[0], want[0]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("case", sorted(SOLVE_CASES))
+def test_compiled_solve_matches_eager_scan_bitwise(case):
+    spec = SOLVE_CASES[case]
+    prob = make_logreg_problem(n_agents=8, q=20, dim=5, seed=2)
+    cfg = FedPLTConfig(**{"solver": GD, **spec.get("cfg", {})})
+    algo = FedPLT(prob, cfg, solver_groups=spec.get("groups"))
+    key = jax.random.PRNGKey(7)
+    got = algo.run_recorded(key, 6)
+    _assert_same_bits(got, _eager_solve(algo, key, 6))
+    # a second call of the length reuses the executable, not a result
+    other = jax.random.PRNGKey(8)
+    _assert_same_bits(algo.run_recorded(other, 6),
+                      _eager_solve(algo, other, 6))
+    if cfg.async_mode == "stale":
+        sched = got[2]
+        assert 0 < float(jnp.sum(sched)) < sched.size
+        _assert_same_bits(algo.replay(key, sched),
+                          _eager_solve(algo, key, 6, sched))

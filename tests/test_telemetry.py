@@ -94,6 +94,29 @@ def test_warm_dense_run_books_every_compile_to_run():
             assert not any(rec[f] for f in COMPILE_FIELDS), (name, rec)
 
 
+def test_dense_run_compiles_once_per_length():
+    prob = make_logreg_problem(n_agents=4, q=10, dim=5, seed=0)
+    # partial participation: the key draws which agents take part
+    tr = build_trainer(prob, FedSpec(gamma=0.05, participation=0.5))
+    key, other = jax.random.PRNGKey(0), jax.random.PRNGKey(1)
+
+    def run(k, n_rounds):
+        return lambda: jax.block_until_ready(tr.run(k, n_rounds))
+
+    (first, _), cold = _window(run(key, 3))
+    assert cold["fedplt.run"]["compiles"] == 1
+    (again, _), warm = _window(run(key, 3))
+    assert warm["fedplt.run"]["compiles"] == 0
+    np.testing.assert_array_equal(np.asarray(again.x), np.asarray(first.x))
+    (moved, _), new_key = _window(run(other, 3))
+    assert new_key["fedplt.run"]["compiles"] == 0
+    assert not np.array_equal(np.asarray(moved.x), np.asarray(first.x))
+    _, longer = _window(run(key, 4))
+    assert longer["fedplt.run"]["compiles"] == 1
+    for d in (cold, warm, new_key, longer):
+        assert d["fedplt.run"]["count"] == 1
+
+
 def test_dense_trainer_spans():
     prob = make_logreg_problem(n_agents=4, q=10, dim=5, seed=0)
     key = jax.random.PRNGKey(0)
