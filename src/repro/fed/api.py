@@ -984,8 +984,12 @@ class ModelTrainer(FedTrainer):
         # state by repro.fed.sharding.fed_state_specs (the one placement
         # source, shared with the dry-run compiler)
         self.mesh = self.spec.build_mesh()
+        # the state is donated: each new leaf may take the old one's
+        # buffer, so a round holds one state, not two, and XLA need not
+        # recompute the solver's forward pass to fit in memory
         self._step = jax.jit(
-            runtime.make_train_step(model, spec, use_remat=use_remat))
+            runtime.make_train_step(model, spec, use_remat=use_remat),
+            donate_argnums=0)
 
     def _state_shardings(self):
         from repro.fed import sharding
@@ -1018,7 +1022,12 @@ class ModelTrainer(FedTrainer):
         recorded (N,) 0/1 schedule row -- broker numerics / replay.
         ``corrupt`` / ``live`` are the broker's fault overrides (see
         :mod:`repro.fed.broker`): per-agent corruption multipliers and
-        the survivor mask after evictions."""
+        the survivor mask after evictions.
+
+        ``state`` is consumed: its buffers are donated to the round and
+        read as deleted afterwards.  Rebind it to the returned state; a
+        caller that needs the old one copies it first
+        (``jax.tree_util.tree_map(jnp.copy, state)``)."""
         with telemetry.span("fedplt.step", step=True):
             return self._step(state, batch, key, arrival, corrupt, live)
 

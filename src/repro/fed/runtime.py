@@ -143,7 +143,10 @@ def init_state(model: Model, key: jax.Array, fcfg) -> FedState:
     """``fcfg`` may be a legacy :class:`FedConfig` or a ``FedSpec``.
 
     Under the packed layout the broadcast parameter stack is packed
-    ONCE here -- the round loop never packs again."""
+    ONCE here -- the round loop never packs again.  ``x``, ``z`` and
+    ``t`` start equal but hold buffers of their own: the trainer
+    donates the state to its round, and one buffer cannot be donated
+    twice."""
     spec = as_spec(fcfg)
     params = model.init(key)
     stacked = jax.tree_util.tree_map(
@@ -152,9 +155,10 @@ def init_state(model: Model, key: jax.Array, fcfg) -> FedState:
         from repro.fed.compress import pack_leaves
 
         stacked = pack_leaves(stacked)[0]
-    t = stacked if spec.compression.name != "none" else None
+    copy = lambda: jax.tree_util.tree_map(jnp.copy, stacked)  # noqa: E731
+    t = copy() if spec.compression.name != "none" else None
     stale = spec.staleness_config().enabled
-    return FedState(x=stacked, z=stacked, step=jnp.zeros((), jnp.int32),
+    return FedState(x=stacked, z=copy(), step=jnp.zeros((), jnp.int32),
                     t=t,
                     y_tag=(jax.tree_util.tree_map(jnp.zeros_like, stacked)
                            if stale else None),

@@ -17,6 +17,9 @@ map from compiled ops to the round's named scopes.
 * :func:`op_scopes` reads a compiled executable's HLO text and maps each
   op to the innermost ``fedplt.*`` scope of its metadata -- the join
   from a device trace's ops to the phases of the round.
+* :func:`compiled_memory` reads a compiled executable's memory plan:
+  how much of its arguments its outputs alias, and how many ops XLA's
+  rematerialisation pass cloned to fit the program in memory.
 
 Device-side scopes are :data:`SCOPES`, put on the round engine's shared
 functions with :func:`scope`.  A span never reads a device value, so it
@@ -181,3 +184,23 @@ def op_scopes(compiled_text: str) -> dict:
         if found:
             out[(module, op)] = found[-1]
     return out
+
+
+_REMAT = re.compile(r"^\s*(?:ROOT )?%?[^\s=]*\.remat[^\s=]* = ", re.M)
+
+
+def compiled_memory(compiled) -> dict:
+    """The memory plan of a ``jax.stages.Compiled``, in bytes:
+    ``argument_bytes``, ``output_bytes``, ``alias_bytes`` (outputs that
+    reuse a donated argument's buffer), ``temp_bytes`` and
+    ``peak_bytes`` (the compiler's own peak; 0 where the backend does
+    not plan one), and ``remat_clones``: HLO instructions whose name
+    holds ``.remat``, the copies XLA's rematerialisation pass makes when
+    the program would not fit in the device's memory otherwise."""
+    m = compiled.memory_analysis()
+    return {"argument_bytes": m.argument_size_in_bytes,
+            "output_bytes": m.output_size_in_bytes,
+            "alias_bytes": m.alias_size_in_bytes,
+            "temp_bytes": m.temp_size_in_bytes,
+            "peak_bytes": m.peak_memory_in_bytes,
+            "remat_clones": len(_REMAT.findall(compiled.as_text()))}

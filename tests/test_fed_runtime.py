@@ -10,6 +10,7 @@ from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.data.synthetic import make_batch_for
 from repro.fed import runtime
+from repro.fed.api import CompressionSpec, FedSpec, build_trainer
 from repro.models.model import build_model
 
 SHAPE = InputShape("tiny", 32, 8, "train")
@@ -105,3 +106,62 @@ def test_runtime_matches_core_fedplt_on_quadratic():
     cstate, _ = core.run(jax.random.PRNGKey(0), 50)
     np.testing.assert_allclose(
         jnp.mean(state.x["x"], axis=0), core.x_bar(cstate), atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# the trainer's round consumes (donates) the state it is given
+# ---------------------------------------------------------------------------
+
+LAYOUTS = {
+    "tree": {},
+    "tree-topk": dict(compression=CompressionSpec("topk")),
+    "packed": dict(state_layout="packed"),
+    "packed-topk": dict(state_layout="packed",
+                        compression=CompressionSpec("topk")),
+    "stale": dict(async_mode="stale", max_staleness=2, participation=0.5),
+    "mesh": dict(mesh_shape="1x1"),
+}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = get_config("gemma2-2b").reduced(n_layers=1, d_model=64, vocab=128)
+    batch = make_batch_for(cfg, InputShape("t", 4, 4, "train"), n_agents=2)
+    return build_model(cfg), batch
+
+
+def _tiny_trainer(tiny, layout):
+    model, batch = tiny
+    spec = FedSpec(n_agents=2, gamma=0.1, n_epochs=2, **LAYOUTS[layout])
+    return build_trainer(model, spec), batch
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_init_buffers_are_distinct_and_step_consumes_them(tiny, layout):
+    tr, batch = _tiny_trainer(tiny, layout)
+    state = tr.init(jax.random.PRNGKey(0))
+    leaves = jax.tree_util.tree_leaves(state)
+    if "topk" in layout:
+        assert state.t is not None
+    ptrs = [leaf.unsafe_buffer_pointer() for leaf in leaves]
+    assert len(set(ptrs)) == len(ptrs)
+    new, m = tr.step(state, batch, jax.random.PRNGKey(1))
+    assert all(leaf.is_deleted() for leaf in leaves)
+    assert np.isfinite(float(m["loss"]))
+    new, _ = tr.step(new, batch, jax.random.PRNGKey(2))
+    assert int(new.step) == 2
+
+
+@pytest.mark.parametrize("layout", ["tree", "packed-topk", "stale"])
+def test_donated_rounds_match_undonated_bitwise(tiny, layout):
+    tr, batch = _tiny_trainer(tiny, layout)
+    plain = jax.jit(runtime.make_train_step(tr.model, tr.spec))
+    key = jax.random.PRNGKey(0)
+    donated, kept = tr.init(key), tr.init(key)
+    for i in range(3):
+        k = jax.random.PRNGKey(10 + i)
+        donated, _ = tr.step(donated, batch, k)
+        kept, _ = plain(kept, batch, k)
+    for a, b in zip(jax.tree_util.tree_leaves((donated.x, donated.z)),
+                    jax.tree_util.tree_leaves((kept.x, kept.z))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

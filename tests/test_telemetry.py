@@ -268,6 +268,33 @@ def test_op_scopes_read_the_innermost_scope():
         ("jit_step", "add.1"): "fedplt.uplink"}
 
 
+def test_compiled_memory_counts_aliases_and_remat_clones():
+    x = jnp.ones((64, 64))
+    f = lambda a, b: (a * 2, b + 1)  # noqa: E731
+    donated = jax.jit(f, donate_argnums=0).lower(x, x).compile()
+    kept = telemetry.compiled_memory(jax.jit(f).lower(x, x).compile())
+    mem = telemetry.compiled_memory(donated)
+    assert mem["argument_bytes"] == 2 * x.nbytes
+    assert mem["alias_bytes"] == x.nbytes and kept["alias_bytes"] == 0
+    assert mem["remat_clones"] == kept["remat_clones"] == 0
+
+    class Rematerialised:
+        """The text of a program XLA cloned ops in to fit memory."""
+        memory_analysis = donated.memory_analysis
+
+        @staticmethod
+        def as_text():
+            return ('  %fusion.464 = f32[4]{0} fusion(%p), kind=kLoop\n'
+                    '  %fusion.464.remat3 = f32[4]{0} fusion(%p), '
+                    'metadata={op_name="jit(step)/mul"}\n'
+                    '  %gte.7 = f32[4]{0} get-tuple-element(%t), '
+                    'metadata={op_name="x.remat"}\n'
+                    '  ROOT %copy.331.remat_compressed = f32[4]{0} '
+                    'copy(%a)\n')
+
+    assert telemetry.compiled_memory(Rematerialised())["remat_clones"] == 2
+
+
 # sha256 (first 16 hex digits) of x, z, t, y, u over two rounds, recorded
 # before the round engine carried any named scope
 CHECKSUMS = {("tree", "plain"): "5cd08e7c655a8575",

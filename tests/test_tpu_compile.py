@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.fed import telemetry
 from repro.fed.compress import packed_meta
 from repro.kernels.compress import ops as compress_ops
 from repro.kernels.fedplt_update.ops import fedplt_update
@@ -134,3 +135,36 @@ def test_sharded_uplink_compiles_on_four_chips(topo):
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
 
+
+
+def test_local_heavy_round_aliases_its_state_without_remat(one_chip):
+    """The model round at the phi4mini.local-heavy shapes (published
+    widths, 2 layers, vocabulary 25,008, float32 state, 2 agents x 4 x
+    512 tokens, N_e 5): the new state aliases the donated one, so the
+    program fits the chip's 15.75 GiB without XLA cloning a single op
+    to recompute it (undonated, the round compiles with 16 clones)."""
+    from repro.configs import get_config
+    from repro.fed.api import FedSpec, build_trainer
+    from repro.models.model import build_model
+
+    full = get_config("phi4-mini-3.8b")
+    cfg = dataclasses.replace(full, n_layers=2, vocab=25008,
+                              dtype="float32")
+    tr = build_trainer(build_model(cfg),
+                       FedSpec(n_agents=2, n_epochs=5, gamma=0.05))
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,  # noqa: E731
+                                           sharding=one_chip)
+    state = jax.tree_util.tree_map(
+        place, jax.eval_shape(tr.init, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((2, 4, 512), jnp.int32, sharding=one_chip)
+    key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    mem = telemetry.compiled_memory(
+        tr.lower(state, {"tokens": tokens, "labels": tokens},
+                 key).compile())
+    # the state's bytes as the chip lays them out (a scalar takes 512)
+    state_bytes = jax.jit(lambda s: s).lower(state).compile() \
+        .memory_analysis().argument_size_in_bytes
+    assert state_bytes > 4 * 2**30
+    assert mem["remat_clones"] == 0
+    assert mem["alias_bytes"] == state_bytes
+    assert mem["peak_bytes"] < 15.75 * 2**30
