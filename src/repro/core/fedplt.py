@@ -19,7 +19,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import prox as prox_lib
 from repro.core.solvers import SolverConfig, local_train
 from repro.fed import engine
 
@@ -40,87 +39,6 @@ class FedPLTState(NamedTuple):
     # carry of repro.fed.async_engine.async_round_step)
     y_tag: Optional[jnp.ndarray] = None     # (N, n)
     staleness: Optional[jnp.ndarray] = None  # (N,) int32
-
-
-@dataclasses.dataclass(frozen=True)
-class FedPLTConfig:
-    rho: float = 1.0
-    solver: SolverConfig = dataclasses.field(default_factory=SolverConfig)
-    participation: float = 1.0        # p (uniform across agents)
-    prox_h: str = "zero"              # coordinator regularizer
-    batch_size: Optional[int] = None  # for sgd oracle
-    # curvature moduli of the f_i; None -> taken from the problem
-    mu: Optional[float] = None
-    L: Optional[float] = None
-    dp_init: bool = False             # x0 ~ N(0, 2 tau^2/mu I)  (Prop. 4)
-    # Remark 1 (uncoordinated solvers): per-agent step sizes tuned to the
-    # LOCAL moduli (mu_i, L_i) instead of the global (min mu_i, max L_i)
-    uncoordinated: bool = False
-    # beyond-paper: compressed z-exchange with lag-based error feedback
-    # (see repro.fed.compress for the registry)
-    compression: str = "none"         # compressor registry name
-    compress_ratio: float = 0.25      # top-k fraction kept
-    compress_energy: float = 0.95     # adaptive_topk per-agent target
-    compress_backend: str = "xla"     # "auto" | "xla" per-leaf | "pallas"
-    engine_backend: str = "xla"       # round edges: "xla" | "pallas" fused
-    # round-to-round state representation: "tree" | "packed" resident
-    # buffer (engine layout contract; dense states are single-leaf, so
-    # the packed form of an (N, n) stack is the same array -- the knob
-    # switches the round arithmetic to the whole-buffer packed path)
-    state_layout: str = "tree"
-    # Krasnosel'skii relaxation: z <- z + 2*damping*(x - y).  damping = 1
-    # is the paper's PRS; damping = 1/2 is Douglas-Rachford -- needed to
-    # stabilize aggressively compressed exchanges (see tests)
-    damping: float = 1.0
-    # bounded-staleness async rounds ("stale"): the participation draw
-    # becomes an arrival draw and stragglers keep training against their
-    # stale reflection up to max_staleness rounds (repro.fed.async_engine;
-    # max_staleness=0 reproduces the synchronous engine bitwise)
-    async_mode: str = "off"
-    max_staleness: int = 0
-    # in-jit increment guards (fault tolerance): screen each agent's
-    # local-solve row at the uplink -- non-finite / over-norm rows
-    # become non-arrivals instead of poisoning the consensus mean
-    guard_increments: bool = False
-    guard_norm_bound: float = float("inf")
-    # coordinator aggregation (repro.fed.robust registry): "mean" keeps
-    # the historical uplink bitwise; robust statistics bound what
-    # finite byzantine increments can do (param: trimmed_mean's trim
-    # count f, norm_clip_mean's clip radius)
-    aggregator: str = "mean"
-    aggregator_param: float = 0.0
-
-    def to_spec(self, n_agents: Optional[int] = None):
-        """The equivalent :class:`repro.fed.api.FedSpec` (the front-door
-        config); ``build_trainer(problem, cfg.to_spec())`` reproduces
-        ``FedPLT(problem, cfg)`` bit-for-bit."""
-        from repro.fed import api
-
-        s = self.solver
-        # the legacy dense solvers only read tau under name="noisy_gd"
-        # (a gd config with tau set ran noiseless); drop the ignored tau
-        # so the spec's tau>0 -> noisy_gd upgrade cannot change behavior
-        tau = s.tau if s.name == "noisy_gd" else 0.0
-        return api.FedSpec(
-            n_agents=n_agents, rho=self.rho,
-            participation=self.participation, damping=self.damping,
-            solver=s.name, n_epochs=s.n_epochs, gamma=s.step_size,
-            mu=self.mu, L=self.L, batch_size=self.batch_size,
-            uncoordinated=self.uncoordinated, prox_h=self.prox_h,
-            privacy=api.PrivacySpec(tau=tau, clip=s.clip,
-                                    dp_init=self.dp_init),
-            compression=api.CompressionSpec(
-                name=self.compression, ratio=self.compress_ratio,
-                energy=self.compress_energy,
-                backend=self.compress_backend),
-            engine_backend=self.engine_backend,
-            state_layout=self.state_layout,
-            async_mode=self.async_mode,
-            max_staleness=self.max_staleness,
-            guard_increments=self.guard_increments,
-            guard_norm_bound=self.guard_norm_bound,
-            aggregator=self.aggregator,
-            aggregator_param=self.aggregator_param)
 
 
 def _jit_data_as_args(fn, static_argnums=()):
@@ -153,89 +71,64 @@ def _jit_data_as_args(fn, static_argnums=()):
 class FedPLT:
     """Paper-faithful Fed-PLT on a vectorized federated problem.
 
-    ``prox_h`` overrides the coordinator regularizer resolved from
-    ``config.prox_h`` (used by the front door to supply registry proxes
-    with bound kwargs, e.g. weight decay).
-
-    ``solver_groups`` partitions the agent axis into heterogeneous
-    groups: a sequence of ``(size, SolverConfig)`` pairs (sizes summing
-    to ``n_agents``), each group running its own solver/epochs/step on
-    its contiguous slice (the paper's "agents choose their local
-    solver").  None -- or one full-size group equal to ``config.solver``
-    -- reproduces the homogeneous trajectory bit-for-bit.
-
-    ``participation`` optionally overrides ``config.participation`` with
-    a per-agent ``(N,)`` tuple of Bernoulli rates.
+    ``spec`` is a :class:`repro.fed.api.FedSpec`; its ``n_agents`` is
+    filled from the problem, and ``mu`` / ``L`` left None are the
+    problem's own moduli.  ``spec.agent_groups`` partitions the agent
+    axis into heterogeneous groups, each running its own solver/epochs/
+    step on its contiguous slice (the paper's "agents choose their local
+    solver"); None reproduces the homogeneous trajectory.  A per-agent
+    ``spec.participation`` tuple gives each agent its own Bernoulli rate.
 
     ``mesh`` (an ``(agent, model)`` :class:`jax.sharding.Mesh`, e.g.
     from :meth:`repro.fed.api.FedSpec.build_mesh`) shards the agent
     axis of every round across the mesh per the engine's mesh contract;
     a 1-device mesh reproduces the unsharded trajectory bitwise."""
 
-    def __init__(self, problem, config: FedPLTConfig, prox_h=None,
-                 solver_groups=None, participation=None, mesh=None):
+    def __init__(self, problem, spec, mesh=None):
+        if spec.n_agents not in (None, problem.n_agents):
+            raise ValueError(f"spec.n_agents={spec.n_agents} != "
+                             f"problem.n_agents={problem.n_agents}")
+        spec = dataclasses.replace(spec, n_agents=problem.n_agents)
         self.problem = problem
-        self.cfg = config
+        self.spec = spec
         self.mesh = mesh
-        self.mu = config.mu if config.mu is not None else problem.strong_convexity()
-        self.L = config.L if config.L is not None else problem.smoothness()
+        # the problem's moduli stay arrays: as Python floats they would
+        # be folded into the compiled solve as constants
+        self.mu = spec.mu if spec.mu is not None else problem.strong_convexity()
+        self.L = spec.L if spec.L is not None else problem.smoothness()
         if self.mu <= 0:  # nonconvex / merely-convex: fall back to 1/rho curvature
             self.mu = 0.0
-        if config.uncoordinated and hasattr(problem,
-                                            "per_agent_smoothness"):
+        if spec.uncoordinated and hasattr(problem, "per_agent_smoothness"):
             self.mu_i = problem.per_agent_strong_convexity()
             self.L_i = problem.per_agent_smoothness()
         else:
             N = problem.n_agents
             self.mu_i = jnp.full((N,), self.mu)
             self.L_i = jnp.full((N,), self.L)
-        self.prox_h = (prox_h if prox_h is not None
-                       else prox_lib.make_prox(config.prox_h))
-        self._ecfg = engine.RoundConfig(
-            n_agents=problem.n_agents, rho=config.rho,
-            participation=(participation if participation is not None
-                           else config.participation),
-            damping=config.damping,
-            compression=config.compression,
-            compress_ratio=config.compress_ratio,
-            compress_energy=config.compress_energy,
-            compress_backend=config.compress_backend,
-            engine_backend=config.engine_backend,
-            state_layout=config.state_layout,
-            staleness=engine.StalenessConfig(
-                mode=config.async_mode,
-                max_staleness=config.max_staleness),
-            agent_shards=engine.mesh_agent_shards(mesh),
-            guard_increments=config.guard_increments,
-            guard_norm_bound=config.guard_norm_bound,
-            aggregator=config.aggregator,
-            aggregator_param=config.aggregator_param)
+        self.prox_h = spec.resolve_prox_h()
+        self._ecfg = spec.round_config()
         # packed layout: the dense state is single-leaf, so its resident
         # (N, n) buffer IS the stacked array (pack_leaves fast path, no
         # lane padding) -- the meta is pure shape arithmetic and the
         # historical solvers consume the buffer unchanged
         self._meta = None
-        if config.state_layout == "packed":
+        if spec.state_layout == "packed":
             from repro.fed import compress as compress_lib
             self._meta = compress_lib.packed_meta(jax.ShapeDtypeStruct(
                 (problem.n_agents, problem.dim), jnp.float32))
-        if solver_groups is None:
+        groups = spec.resolved_groups()
+        if groups is None:
             # the homogeneous path is the single full-size group; a
             # [0:N] slice is a no-op, so this is bit-identical to the
-            # historical dedicated path (asserted in tests/test_api.py)
+            # historical dedicated path
             self._solvers = self._make_group_solver(
-                0, problem.n_agents, config.solver)
+                0, problem.n_agents, spec.solver_config())
         else:
-            sizes = [s for s, _ in solver_groups]
-            if sum(sizes) != problem.n_agents:
-                raise ValueError(
-                    f"solver_groups sizes sum to {sum(sizes)}, problem "
-                    f"has n_agents={problem.n_agents}")
             self._solvers, start = [], 0
-            for size, scfg in solver_groups:
+            for g, scfg in zip(groups, spec.group_solver_configs()):
                 self._solvers.append(engine.SolverGroup(
-                    size, self._make_group_solver(start, size, scfg)))
-                start += size
+                    g.size, self._make_group_solver(start, g.size, scfg)))
+                start += g.size
             self._solvers = tuple(self._solvers)
         self._round = jax.jit(self._round_impl)
         self._round_arrival = jax.jit(self._round_core)
@@ -249,8 +142,9 @@ class FedPLT:
     def init(self, key: jax.Array) -> FedPLTState:
         N, n = self.problem.n_agents, self.problem.dim
         k_init, k_state = jax.random.split(key)
-        if self.cfg.dp_init and self.cfg.solver.tau > 0 and self.mu > 0:
-            std = jnp.sqrt(2.0 * self.cfg.solver.tau ** 2 / self.mu)
+        tau = self.spec.privacy.tau
+        if self.spec.privacy.dp_init and tau > 0 and self.mu > 0:
+            std = jnp.sqrt(2.0 * tau ** 2 / self.mu)
             # the barrier keeps XLA from folding std into the draw's own
             # constants when init is compiled, so x0 has the same bits
             # compiled as op by op
@@ -269,12 +163,12 @@ class FedPLT:
                                       if stale else None))
 
     # ------------------------------------------------------------------
-    def _fgrad(self, data, w, key, scfg=None):
+    def _fgrad(self, data, w, key, scfg):
         """Per-agent gradient oracle (full or minibatch)."""
-        scfg = scfg if scfg is not None else self.cfg.solver
-        if scfg.name == "sgd" and self.cfg.batch_size is not None:
+        batch_size = self.spec.batch_size
+        if scfg.name == "sgd" and batch_size is not None:
             q = data[0].shape[0]
-            idx = jax.random.randint(key, (self.cfg.batch_size,), 0, q)
+            idx = jax.random.randint(key, (batch_size,), 0, q)
             return self.problem.minibatch_grad(data, w, idx)
         return jax.grad(lambda xx: self.problem.local_loss(data, xx))(w)
 
@@ -312,7 +206,7 @@ class FedPLT:
                         data_g, w_stack, keys)
 
             return solver_registry.make_local_solver(
-                scfg, fgrad_stacked, self.cfg.rho, self.mu, self.L)
+                scfg, fgrad_stacked, self.spec.rho, self.mu, self.L)
 
         def solver(x_g, v_g, k_solve):
             solver_keys = jax.random.split(k_solve, size)
@@ -320,7 +214,7 @@ class FedPLT:
 
             def one_agent(data_i, x_i, v_i, key_i, mu_i, L_i):
                 fgrad = lambda w, k: self._fgrad(data_i, w, k, scfg)
-                return local_train(fgrad, x_i, v_i, self.cfg.rho, scfg,
+                return local_train(fgrad, x_i, v_i, self.spec.rho, scfg,
                                    key_i, mu_i, L_i)
 
             w = jax.vmap(one_agent)(data_g, x_g, v_g, solver_keys,

@@ -1,10 +1,7 @@
 """One front door for Fed-PLT: ``FedSpec`` + ``build_trainer``.
 
-The historical configs (``FedPLTConfig`` for the dense paper
-experiments, ``FedConfig`` for model scale, plus the engine's
-``RoundConfig`` and the solvers' ``SolverConfig``) redeclared
-overlapping knobs and validated them in three different places.
-``FedSpec`` is the single composable spec:
+``FedSpec`` is the one Fed-PLT configuration, on both paths (the dense
+paper experiments and model scale), composed as:
 
     round topology   -- n_agents / rho / participation / damping
     local solver     -- solver / n_epochs / gamma / (mu, L)
@@ -12,16 +9,17 @@ overlapping knobs and validated them in three different places.
     uplink           -- :class:`CompressionSpec` (registry name + knobs)
     coordinator h    -- prox_h registry name (+ weight_decay shorthand)
 
-with ONE :meth:`FedSpec.validate` owning every cross-field check, and
-:func:`build_trainer` dispatching to either front end behind one handle:
+with ONE :meth:`FedSpec.validate` owning every cross-field check,
+:meth:`FedSpec.round_config` the one translation into the engine's
+:class:`repro.fed.engine.RoundConfig`, and :func:`build_trainer`
+dispatching to either front end behind one handle:
 
     >>> spec = FedSpec(n_agents=4, gamma=0.1, n_epochs=3)
     >>> trainer = build_trainer(problem_or_model, spec)
     >>> state, history = trainer.run(jax.random.PRNGKey(0), 100)
 
-Both legacy configs now expose ``.to_spec()`` and stay bit-compatible:
-``build_trainer(problem, cfg.to_spec())`` reproduces
-``FedPLT(problem, cfg)`` trajectories exactly.
+A round knob is a spec field, its check in ``validate`` and its line in
+``round_config``.
 
 The CLI in :mod:`repro.launch.train` is *generated* from the spec's
 dataclass fields (:func:`add_spec_args` / :func:`spec_from_args`), so a
@@ -34,7 +32,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-from typing import Any, Optional, Sequence, Union
+from typing import Optional, Sequence, Union
 
 import jax
 
@@ -193,8 +191,13 @@ class FedSpec:
         help="number of agents (dense path: taken from the problem)"))
     rho: float = dataclasses.field(default=1.0, metadata=_cli(
         help="proximal penalty rho of Algorithm 1"))
-    participation: float = dataclasses.field(default=1.0, metadata=_cli(
-        help="per-agent Bernoulli participation probability p"))
+    # a float (every agent) or a per-agent sequence of N rates, kept as a
+    # tuple; 0 marks an agent that never arrives.  The generated flag
+    # takes the float.
+    participation: Union[float, tuple[float, ...]] = dataclasses.field(
+        default=1.0, metadata=_cli(
+            arg_type=float,
+            help="per-agent Bernoulli participation probability p"))
     damping: float = dataclasses.field(default=1.0, metadata=_cli(
         help="Krasnosel'skii relaxation (1 = PRS, 0.5 = Douglas-Rachford)"))
     # -- local solver ----------------------------------------------------
@@ -316,6 +319,13 @@ class FedSpec:
              "(default: agent-shards x 1)"))
 
     def __post_init__(self):
+        p = self.participation
+        if isinstance(p, (list, tuple)) or getattr(p, "ndim", 0) > 0:
+            # any per-agent sequence (list, array) takes one form
+            object.__setattr__(self, "participation",
+                               tuple(float(q) for q in p))
+        elif getattr(p, "ndim", None) == 0:
+            object.__setattr__(self, "participation", float(p))
         groups = self.agent_groups
         if groups is not None:
             if isinstance(groups, str):
@@ -362,8 +372,10 @@ class FedSpec:
             for g in groups)
 
     def participation_schedule(self) -> Union[float, tuple[float, ...]]:
-        """Engine participation: the scalar p, or the per-agent (N,)
-        tuple expanded from the groups when any group deviates."""
+        """Engine participation: the scalar p, the per-agent (N,) tuple
+        as given, or one expanded from the groups when any deviates."""
+        if isinstance(self.participation, tuple):
+            return self.participation
         groups = self.resolved_groups()
         if groups is None or all(
                 g.participation == self.participation for g in groups):
@@ -497,8 +509,7 @@ class FedSpec:
             raise ValueError("n_agents must be >= 1")
         if self.rho <= 0.0:
             raise ValueError("rho must be positive")
-        if not 0.0 < self.participation <= 1.0:
-            raise ValueError("participation must be in (0, 1]")
+        self._validate_participation()
         if not 0.0 < self.damping <= 1.0:
             raise ValueError("damping must be in (0, 1]")
         if self.n_epochs < 1:
@@ -564,6 +575,24 @@ class FedSpec:
                 f"= {self.rho / (1.0 + mu * self.rho):.4g}) -- pass "
                 f"an explicit L in the spec{where}")
 
+    def _validate_participation(self) -> None:
+        p = self.participation
+        if not isinstance(p, tuple):
+            if isinstance(p, (str, bytes)) or not 0.0 < p <= 1.0:
+                raise ValueError(f"participation must be in (0, 1], "
+                                 f"got {p!r}")
+            return
+        if any(not 0.0 <= q <= 1.0 for q in p):
+            raise ValueError(f"per-agent participation entries must be in "
+                             f"[0, 1], got {p}")
+        if self.n_agents is not None and len(p) != self.n_agents:
+            raise ValueError(f"per-agent participation has {len(p)} "
+                             f"entries for n_agents={self.n_agents}")
+        if self.agent_groups is not None:
+            raise ValueError("a per-agent participation tuple and "
+                             "agent_groups are mutually exclusive (set "
+                             "participation per group)")
+
     def _validate_groups(self) -> None:
         groups = self.resolved_groups()
         if groups is None:
@@ -622,49 +651,6 @@ class FedSpec:
                         f"a device boundary; re-cut the groups or "
                         f"change the shard count")
 
-    # ------------------------------------------------------------------
-    # Legacy-config bridge (kept bit-compatible)
-    # ------------------------------------------------------------------
-    def to_dense_config(self):
-        """The :class:`repro.core.fedplt.FedPLTConfig` this spec denotes
-        (inverse of ``FedPLTConfig.to_spec``, used by the dense trainer
-        so trajectories stay bit-identical to the legacy front end)."""
-        from repro.core.fedplt import FedPLTConfig
-
-        return FedPLTConfig(
-            rho=self.rho,
-            solver=self.solver_config(),
-            participation=self.participation,
-            prox_h=self.prox_h,
-            batch_size=self.batch_size,
-            mu=self.mu, L=self.L,
-            dp_init=self.privacy.dp_init,
-            uncoordinated=self.uncoordinated,
-            compression=self.compression.name,
-            compress_ratio=self.compression.ratio,
-            compress_energy=self.compression.energy,
-            compress_backend=self.compression.backend,
-            engine_backend=self.engine_backend,
-            state_layout=self.state_layout,
-            damping=self.damping,
-            async_mode=self.async_mode,
-            max_staleness=self.max_staleness,
-            guard_increments=self.guard_increments,
-            guard_norm_bound=self.guard_norm_bound,
-            aggregator=self.aggregator,
-            aggregator_param=self.aggregator_param)
-
-
-def as_spec(cfg: Any) -> FedSpec:
-    """Normalize a FedSpec / FedPLTConfig / FedConfig to a FedSpec."""
-    if isinstance(cfg, FedSpec):
-        return cfg
-    to_spec = getattr(cfg, "to_spec", None)
-    if to_spec is None:
-        raise TypeError(f"cannot interpret {type(cfg).__name__} as a "
-                        f"FedSpec (no .to_spec())")
-    return to_spec()
-
 
 # ---------------------------------------------------------------------------
 # Privacy accounting from the spec
@@ -683,7 +669,7 @@ def _resolve_gamma(spec: "FedSpec", gamma: Optional[float]) -> float:
         m + 1.0 / spec.rho, L + 1.0 / spec.rho)
 
 
-def privacy_report(spec: Any, n_rounds: int,
+def privacy_report(spec: FedSpec, n_rounds: int,
                    local_dataset_size: Union[int, Sequence[int]],
                    delta: Optional[float] = None, *,
                    mu: Optional[float] = None):
@@ -715,7 +701,7 @@ def privacy_report(spec: Any, n_rounds: int,
     """
     from repro.core.privacy import PrivacyReport
 
-    spec = as_spec(spec).validate()
+    spec = spec.validate()
     p = spec.privacy
     if p.tau <= 0.0:
         raise ValueError("privacy_report requires tau > 0")
@@ -778,7 +764,7 @@ def _per_agent_inputs(spec: "FedSpec", qs, local_dataset_size):
     return qs, gammas, epochs, sensitivities
 
 
-def effective_privacy_report(spec: Any, schedule,
+def effective_privacy_report(spec: FedSpec, schedule,
                              local_dataset_size: Union[int, Sequence[int]],
                              delta: Optional[float] = None, *,
                              mu: Optional[float] = None):
@@ -800,7 +786,7 @@ def effective_privacy_report(spec: Any, schedule,
     from repro.core.privacy import PrivacyReport
     from repro.fed.async_engine import effective_counts
 
-    spec = as_spec(spec).validate()
+    spec = spec.validate()
     p = spec.privacy
     if p.tau <= 0.0:
         raise ValueError("effective_privacy_report requires tau > 0")
@@ -865,40 +851,24 @@ class FedTrainer:
 
 
 class DenseTrainer(FedTrainer):
-    """:class:`repro.core.fedplt.FedPLT` behind the FedTrainer handle --
-    trajectories are bit-identical to the legacy front end."""
+    """:class:`repro.core.fedplt.FedPLT` behind the FedTrainer handle."""
 
     def __init__(self, problem, spec: FedSpec):
-        if spec.n_agents not in (None, problem.n_agents):
-            raise ValueError(f"spec.n_agents={spec.n_agents} != "
-                             f"problem.n_agents={problem.n_agents}")
-        self.spec = dataclasses.replace(spec, n_agents=problem.n_agents)
-        # the spec with the problem's actual curvature filled in --
-        # validation and privacy accounting both need the real moduli
+        from repro.core.fedplt import FedPLT
+
+        # FedPLT fills n_agents from the problem (and rejects a
+        # mismatch); the spec validated here also carries the actual
+        # curvature, which validation and privacy accounting need as
+        # numbers (FedPLT keeps the moduli as arrays)
+        self.problem = problem
+        self.algo = FedPLT(problem, spec, mesh=spec.build_mesh())
+        self.spec = self.algo.spec
         self._resolved = dataclasses.replace(
             self.spec,
             mu=spec.mu if spec.mu is not None
             else float(problem.strong_convexity()),
             L=spec.L if spec.L is not None
             else float(problem.smoothness())).validate()
-        from repro.core.fedplt import FedPLT
-
-        prox_override = (self.spec.resolve_prox_h()
-                         if self.spec.weight_decay != 0.0 else None)
-        groups = self._resolved.resolved_groups()
-        solver_groups = None
-        if groups is not None:
-            solver_groups = tuple(
-                (g.size, scfg) for g, scfg in zip(
-                    groups, self._resolved.group_solver_configs()))
-        part = self._resolved.participation_schedule()
-        self.problem = problem
-        self.algo = FedPLT(problem, self.spec.to_dense_config(),
-                           prox_h=prox_override,
-                           solver_groups=solver_groups,
-                           participation=part if isinstance(part, tuple)
-                           else None,
-                           mesh=self._resolved.build_mesh())
 
     def init(self, key: jax.Array):
         with telemetry.span("fedplt.init"):
@@ -963,7 +933,7 @@ class DenseTrainer(FedTrainer):
 class ModelTrainer(FedTrainer):
     """:mod:`repro.fed.runtime` behind the FedTrainer handle."""
 
-    def __init__(self, model, spec: FedSpec, use_remat: bool = True):
+    def __init__(self, model, spec: FedSpec):
         if spec.n_agents is None:
             raise ValueError("FedSpec.n_agents is required at model scale")
         if spec.gamma is None:
@@ -988,7 +958,7 @@ class ModelTrainer(FedTrainer):
         # buffer, so a round holds one state, not two, and XLA need not
         # recompute the solver's forward pass to fit in memory
         self._step = jax.jit(
-            runtime.make_train_step(model, spec, use_remat=use_remat),
+            runtime.make_train_step(model, spec),
             donate_argnums=0)
 
     def _state_shardings(self):
@@ -1078,16 +1048,17 @@ class ModelTrainer(FedTrainer):
                               delta)
 
 
-def build_trainer(problem_or_model, spec: Any) -> FedTrainer:
+def build_trainer(problem_or_model, spec: FedSpec) -> FedTrainer:
     """The front door: a unified trainer over both Fed-PLT paths.
 
     Dense convex problems (``local_loss`` + ``n_agents``; see
     :mod:`repro.core.problem`) get the paper-faithful ``FedPLT`` engine
     front end; model objects (``init`` + ``loss_fn``; see
-    :mod:`repro.models.model`) get the model-scale runtime.  ``spec``
-    may be a :class:`FedSpec` or any legacy config with ``.to_spec()``.
+    :mod:`repro.models.model`) get the model-scale runtime.
     """
-    spec = as_spec(spec)
+    if not isinstance(spec, FedSpec):
+        raise TypeError(f"build_trainer takes a FedSpec, not "
+                        f"{type(spec).__name__}")
     with telemetry.span("fedplt.build"):
         if hasattr(problem_or_model, "local_loss") and \
                 hasattr(problem_or_model, "n_agents"):

@@ -14,8 +14,8 @@ New compressors plug in through :func:`register_compressor`::
         scale = jnp.mean(jnp.abs(dz), axis=-1, keepdims=True)
         return jnp.sign(dz) * scale
 
-and are immediately reachable from every front end (``FedSpec``,
-``FedPLTConfig``, ``FedConfig``, the train CLI) by name -- the engine
+and are immediately reachable by name from ``FedSpec`` and the train
+CLI generated from it -- the engine
 dispatches through this registry, never through hard-coded branches.
 
 The registered function receives the :class:`repro.fed.engine.RoundConfig`

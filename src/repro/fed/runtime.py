@@ -20,19 +20,21 @@ engine (:mod:`repro.fed.engine`):
 The gradient grad f_i is computed on the agent's local batch, vmapped over
 the agent axis; within an agent, activations shard over 'model' (+'data'
 in multi-pod fed mode).
+
+Every entry point takes a :class:`repro.fed.api.FedSpec`;
+:func:`make_train_step` validates it and takes the round's engine config
+from ``spec.round_config()``.
 """
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Any, NamedTuple, Optional
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.fed import engine
-from repro.fed.api import FedSpec, as_spec
-from repro.fed.api import privacy_report as _spec_privacy_report
+from repro.fed.api import FedSpec
 from repro.models.model import Model
 
 
@@ -60,70 +62,7 @@ class FedState(NamedTuple):
     staleness: Any = None   # (A,) int32
 
 
-@dataclasses.dataclass(frozen=True)
-class FedConfig:
-    """Thin legacy shim over :class:`repro.fed.api.FedSpec`.
-
-    Kept for existing call sites; every runtime entry point normalizes
-    it via :meth:`to_spec`, and all validation lives in
-    ``FedSpec.validate`` -- prefer constructing a ``FedSpec`` (or
-    ``api.build_trainer``) directly in new code.
-    """
-
-    n_agents: int = 16
-    rho: float = 1.0
-    gamma: float = 0.05
-    n_epochs: int = 5
-    participation: float = 1.0
-    tau: float = 0.0                 # DP noise std (forces noisy local GD)
-    clip: Optional[float] = None     # per-agent gradient clipping
-    weight_decay: float = 0.0        # coordinator prox: l2 regularizer h
-    use_pallas_update: bool = False  # fused fedplt_update kernel for the
-    #   local step (interpret-mode on CPU; real kernel on TPU)
-    solver: str = "gd"               # gd | agd | sgd (tau>0 -> noisy_gd)
-    # curvature moduli of the local losses; 0 -> derived from gamma so
-    # that agd's 1/L_d step equals gamma
-    mu: float = 0.0
-    L: float = 0.0
-    compression: str = "none"        # z-uplink compressor registry name
-    compress_ratio: float = 0.25
-    compress_backend: str = "xla"    # "auto" | "xla" per-leaf | "pallas"
-    engine_backend: str = "xla"      # round edges: "xla" | "pallas" fused
-    state_layout: str = "tree"       # "tree" | "packed" resident buffer
-    damping: float = 1.0             # Krasnosel'skii relaxation
-    async_mode: str = "off"          # "off" | "stale" bounded staleness
-    max_staleness: int = 0           # K: forced arrival bound
-    guard_increments: bool = False   # in-jit finite/norm screen on uplinks
-    guard_norm_bound: float = float("inf")  # inf = finiteness-only screen
-    aggregator: str = "mean"         # repro.fed.robust registry name
-    aggregator_param: float = 0.0    # trim count f / clip radius
-
-    def to_spec(self) -> FedSpec:
-        from repro.fed.api import CompressionSpec, PrivacySpec
-
-        return FedSpec(
-            n_agents=self.n_agents, rho=self.rho,
-            participation=self.participation, damping=self.damping,
-            solver=self.solver, n_epochs=self.n_epochs, gamma=self.gamma,
-            mu=self.mu if self.mu != 0.0 else None,
-            L=self.L if self.L > 0.0 else None,
-            weight_decay=self.weight_decay,
-            privacy=PrivacySpec(tau=self.tau, clip=self.clip),
-            compression=CompressionSpec(name=self.compression,
-                                        ratio=self.compress_ratio,
-                                        backend=self.compress_backend),
-            engine_backend=self.engine_backend,
-            state_layout=self.state_layout,
-            use_pallas=self.use_pallas_update,
-            async_mode=self.async_mode,
-            max_staleness=self.max_staleness,
-            guard_increments=self.guard_increments,
-            guard_norm_bound=self.guard_norm_bound,
-            aggregator=self.aggregator,
-            aggregator_param=self.aggregator_param)
-
-
-def packed_layout(model: Model, fcfg):
+def packed_layout(model: Model, spec: FedSpec):
     """The static :class:`repro.fed.compress.PackedMeta` of a model's
     agent-stacked state -- pure shape arithmetic over
     ``jax.eval_shape(model.init)``, so no parameters are materialized.
@@ -131,7 +70,6 @@ def packed_layout(model: Model, fcfg):
     boundary)."""
     from repro.fed import compress as compress_lib
 
-    spec = as_spec(fcfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     stacked = jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct((spec.n_agents,) + s.shape,
@@ -139,15 +77,14 @@ def packed_layout(model: Model, fcfg):
     return compress_lib.packed_meta(stacked)
 
 
-def init_state(model: Model, key: jax.Array, fcfg) -> FedState:
-    """``fcfg`` may be a legacy :class:`FedConfig` or a ``FedSpec``.
+def init_state(model: Model, key: jax.Array, spec: FedSpec) -> FedState:
+    """The agent-stacked starting state of a run.
 
     Under the packed layout the broadcast parameter stack is packed
     ONCE here -- the round loop never packs again.  ``x``, ``z`` and
     ``t`` start equal but hold buffers of their own: the trainer
     donates the state to its round, and one buffer cannot be donated
     twice."""
-    spec = as_spec(fcfg)
     params = model.init(key)
     stacked = jax.tree_util.tree_map(
         lambda p: jnp.broadcast_to(p, (spec.n_agents,) + p.shape), params)
@@ -166,24 +103,12 @@ def init_state(model: Model, key: jax.Array, fcfg) -> FedState:
                                if stale else None))
 
 
-def _coordinator_prox(zbar, fcfg):
-    """Apply the coordinator prox to an agent-mean pytree (convenience /
-    test hook; delegates to the same registry ProxH the engine uses)."""
-    spec = as_spec(fcfg)
-    prox = spec.resolve_prox_h()
-    if prox is None:
-        return zbar
-    rho_eff = spec.rho / spec.n_agents
-    return jax.tree_util.tree_map(lambda t: prox(t, rho_eff), zbar)
-
-
-def make_train_step(model: Model, fcfg, use_remat: bool = True):
+def make_train_step(model: Model, spec: FedSpec):
     """Returns ``step(state, batch, key) -> (state, metrics)``.
 
-    ``fcfg`` may be a legacy :class:`FedConfig` or a ``FedSpec``;
     ``batch`` leaves carry a leading agent axis: tokens (A, b, S), etc.
     """
-    spec = as_spec(fcfg).validate()   # the ONE validation site
+    spec = spec.validate()            # the ONE validation site
     scfg = spec.solver_config()
     ecfg = spec.round_config()
     mesh = spec.build_mesh()          # None = unsharded rounds
@@ -210,7 +135,7 @@ def make_train_step(model: Model, fcfg, use_remat: bool = True):
                 use_pallas=spec.use_pallas, has_aux=True)
 
     def per_agent_loss(params_i, batch_i):
-        return model.loss_fn(params_i, batch=batch_i, remat=use_remat)
+        return model.loss_fn(params_i, batch=batch_i, remat=True)
 
     grad_fn = jax.value_and_grad(per_agent_loss)
 
@@ -318,18 +243,3 @@ def consensus_model(state: FedState, meta=None):
 
         x = unpack_leaves(x, meta)
     return jax.tree_util.tree_map(lambda l: jnp.mean(l, axis=0), x)
-
-
-def privacy_report(fcfg, n_rounds: int, local_dataset_size: int,
-                   delta: float = 1e-5):
-    """Position a DP training run on the paper's (eps, delta) map.
-
-    Thin delegate to :func:`repro.fed.api.privacy_report` (one
-    accountant for both front ends); at model scale the local losses are
-    nonconvex, so it accounts with the curvature the algorithm actually
-    optimizes against (the proximal term gives d_i strong convexity
-    >= weight_decay + 1/rho).  See the api docstring for the
-    sensitivity convention.
-    """
-    return _spec_privacy_report(as_spec(fcfg), n_rounds,
-                                local_dataset_size, delta)

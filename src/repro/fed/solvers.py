@@ -6,8 +6,8 @@ solver dispatch mirrors the uplink-compressor registry
 (:func:`repro.fed.compress.register_compressor`): a name maps to a
 *factory* that builds an :data:`repro.fed.engine.LocalSolver` from a
 :class:`repro.core.solvers.SolverConfig` plus the gradient oracle, and
-every front end (``FedSpec``, the legacy shims, the generated train CLI)
-reaches registered solvers by name.  Heterogeneous deployments assign a
+``FedSpec`` and the train CLI generated from it reach registered
+solvers by name.  Heterogeneous deployments assign a
 different registered solver (and epochs / step size) to each agent
 group; see ``FedSpec.agent_groups``.
 

@@ -29,6 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import ARCH_IDS, SHAPES, get_config  # noqa: E402
 from repro.fed import runtime, sharding  # noqa: E402
+from repro.fed.api import FedSpec, PrivacySpec  # noqa: E402
 from repro.launch import roofline  # noqa: E402
 from repro.launch.mesh import make_production_mesh, mesh_axis_sizes  # noqa: E402
 from repro.models import model as model_lib  # noqa: E402
@@ -72,15 +73,15 @@ def build_case(arch: str, shape_name: str, mesh, mode: str = "fed",
         # fed_batch_specs (shared with build_trainer)
         agent_axis, fsdp_axis = sharding.fed_axes(axes)
         n_agents = axes[agent_axis]
-        fcfg = runtime.FedConfig(n_agents=n_agents, n_epochs=n_epochs,
-                                 tau=1e-3, participation=0.8)
-        step = runtime.make_train_step(model, fcfg, use_remat=True)
+        spec = FedSpec(n_agents=n_agents, n_epochs=n_epochs, gamma=0.05,
+                       participation=0.8, privacy=PrivacySpec(tau=1e-3))
+        step = runtime.make_train_step(model, spec)
         state_shape = jax.eval_shape(
-            partial(runtime.init_state, model, fcfg=fcfg),
+            partial(runtime.init_state, model, spec=spec),
             jax.random.PRNGKey(0))
         state_spec = sharding.fed_state_specs(
             state_shape.x, fsdp_axis=fsdp_axis, agent_axis=agent_axis,
-            axis_sizes=axes, compressed=fcfg.compression != "none")
+            axis_sizes=axes, compressed=spec.compression.name != "none")
         # batch: (A, B/A, S): per-agent batch shards over 'data' when the
         # agent axis is dedicated ('agent'/'pod'), else unsharded
         inner_axis = "data" if agent_axis != "data" else None

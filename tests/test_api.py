@@ -1,5 +1,5 @@
-"""The Fed-PLT front door: FedSpec validation, build_trainer equivalence
-with the legacy front ends, the generated CLI, and the compressor
+"""The Fed-PLT front door: FedSpec validation, the spec's round knobs
+reaching the engine on both paths, the generated CLI, and the compressor
 registry (including the per-agent adaptive compressor at model scale)."""
 
 import dataclasses
@@ -9,13 +9,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.fedplt import FedPLT, FedPLTConfig
 from repro.core.problem import make_quadratic_problem
 from repro.core.prox import make_prox
-from repro.core.solvers import SolverConfig
-from repro.fed import runtime
+from repro.fed import engine, runtime
 from repro.fed.api import (CompressionSpec, FedSpec, PrivacySpec,
-                           add_spec_args, build_trainer, spec_from_args)
+                           add_spec_args, build_trainer, privacy_report,
+                           spec_from_args)
 from repro.fed.compress import (available_compressors, get_compressor,
                                 register_compressor)
 
@@ -41,46 +40,8 @@ def _quad_batch(quad):
 
 
 # ---------------------------------------------------------------------------
-# Dense path: build_trainer == FedPLT, bit for bit
+# Dense path
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("cfg_kw,solver_kw", [
-    (dict(), dict(name="gd")),                                   # plain gd
-    (dict(), dict(name="noisy_gd", tau=0.05)),                   # DP noise
-    # legacy quirk: gd with tau set ran NOISELESS (tau read only by
-    # noisy_gd) -- to_spec must not let the tau>0 upgrade change that
-    (dict(), dict(name="gd", tau=0.1)),
-    (dict(participation=0.6), dict(name="gd")),                  # partial
-    (dict(participation=0.7, compression="topk", compress_ratio=0.5,
-          damping=0.5), dict(name="gd")),                        # topk + pp
-])
-def test_build_trainer_matches_fedplt_bit_for_bit(quad, cfg_kw, solver_kw):
-    """FedPLT(problem, cfg).run == build_trainer(problem,
-    cfg.to_spec()).run -- same PRNG stream, same ops, same bits."""
-    cfg = FedPLTConfig(rho=1.0,
-                       solver=SolverConfig(n_epochs=3, **solver_kw),
-                       **cfg_kw)
-    key = jax.random.PRNGKey(11)
-    s_ref, c_ref = FedPLT(quad, cfg).run(key, 25)
-    trainer = build_trainer(quad, cfg.to_spec())
-    s_new, c_new = trainer.run(key, 25)
-    np.testing.assert_array_equal(np.asarray(c_ref), np.asarray(c_new))
-    np.testing.assert_array_equal(np.asarray(s_ref.x), np.asarray(s_new.x))
-    np.testing.assert_array_equal(np.asarray(s_ref.z), np.asarray(s_new.z))
-
-
-def test_dense_config_roundtrip_is_identity(quad):
-    for cfg in [
-        FedPLTConfig(),
-        FedPLTConfig(rho=0.5, prox_h="l1", batch_size=16,
-                     solver=SolverConfig(name="sgd", n_epochs=7)),
-        FedPLTConfig(mu=0.1, L=5.0, dp_init=True, uncoordinated=True,
-                     solver=SolverConfig(name="noisy_gd", tau=0.2,
-                                         step_size=0.03), damping=0.5,
-                     compression="int8", participation=0.4),
-    ]:
-        assert cfg.to_spec().to_dense_config() == cfg
-
 
 def test_dense_state_t_materialized_only_when_compressed(quad):
     uncompressed = build_trainer(quad, FedSpec(rho=1.0))
@@ -104,29 +65,6 @@ def test_dense_trainer_consensus_and_report(quad):
     assert np.isfinite(rep.adp_eps) and rep.adp_eps > 0
 
 
-# ---------------------------------------------------------------------------
-# Model path: FedConfig shim == FedSpec through make_train_step
-# ---------------------------------------------------------------------------
-
-def test_fedconfig_to_spec_train_step_equivalent(quad):
-    fcfg = runtime.FedConfig(n_agents=5, gamma=0.05, n_epochs=3,
-                             weight_decay=0.1, compression="topk",
-                             compress_ratio=0.5)
-    batch = _quad_batch(quad)
-
-    def losses(cfg_like):
-        state = runtime.init_state(QuadModel(), jax.random.PRNGKey(0),
-                                   cfg_like)
-        step = jax.jit(runtime.make_train_step(QuadModel(), cfg_like))
-        out = []
-        for i in range(4):
-            state, m = step(state, batch, jax.random.PRNGKey(i))
-            out.append(float(m["loss"]))
-        return out
-
-    np.testing.assert_array_equal(losses(fcfg), losses(fcfg.to_spec()))
-
-
 def test_weight_decay_prox_shared_registry():
     """The model path's weight decay is the core/prox.py registry entry:
     one ProxH convention for both fronts."""
@@ -135,9 +73,11 @@ def test_weight_decay_prox_shared_registry():
     np.testing.assert_array_equal(
         make_prox("weight_decay", weight=0.3)(y, 0.5),
         y * (1.0 / (1.0 + 0.3 * 0.5)))
-    fcfg = runtime.FedConfig(n_agents=2, weight_decay=0.3, rho=1.0)
+    spec = FedSpec(n_agents=2, gamma=0.05, weight_decay=0.3, rho=1.0)
     np.testing.assert_array_equal(
-        runtime._coordinator_prox({"w": y}, fcfg)["w"],
+        engine.coordinator_prox({"w": jnp.stack([y, y])},
+                                spec.round_config(),
+                                spec.resolve_prox_h())["w"],
         y * (1.0 / (1.0 + 0.3 * (1.0 / 2))))
 
 
@@ -149,13 +89,14 @@ def test_clip_validation_raised_once_from_spec():
     with pytest.raises(ValueError, match="clip must be positive"):
         FedSpec(n_agents=2, gamma=0.1,
                 privacy=PrivacySpec(clip=0.0)).validate()
-    # ... and still fails fast at the legacy call sites
+    # ... and fails fast at the runtime and the accountant
     with pytest.raises(ValueError, match="clip must be positive"):
-        runtime.make_train_step(QuadModel(),
-                                runtime.FedConfig(n_agents=2, clip=0.0))
+        runtime.make_train_step(QuadModel(), FedSpec(
+            n_agents=2, gamma=0.05, privacy=PrivacySpec(clip=0.0)))
     with pytest.raises(ValueError, match="clip must be positive"):
-        runtime.privacy_report(
-            runtime.FedConfig(n_agents=2, tau=0.1, clip=-1.0), 10, 10)
+        privacy_report(FedSpec(n_agents=2, gamma=0.05,
+                               privacy=PrivacySpec(tau=0.1, clip=-1.0)),
+                       10, 10)
 
 
 def test_agd_moduli_validation_raised_once_from_spec():
@@ -164,8 +105,7 @@ def test_agd_moduli_validation_raised_once_from_spec():
         FedSpec(n_agents=2, solver="agd", gamma=2.0).validate()
     with pytest.raises(ValueError, match="agd momentum needs L > mu"):
         runtime.make_train_step(
-            QuadModel(), runtime.FedConfig(n_agents=2, solver="agd",
-                                           gamma=2.0))
+            QuadModel(), FedSpec(n_agents=2, solver="agd", gamma=2.0))
     with pytest.raises(ValueError, match="agd momentum needs L > mu"):
         FedSpec(n_agents=2, solver="agd", mu=2.0, L=1.0).validate()
 
@@ -178,7 +118,7 @@ def test_agd_with_dp_noise_rejected():
 
 def test_privacy_report_requires_tau():
     with pytest.raises(ValueError, match="requires tau > 0"):
-        runtime.privacy_report(runtime.FedConfig(n_agents=2), 10, 10)
+        privacy_report(FedSpec(n_agents=2, gamma=0.05), 10, 10)
 
 
 def test_unknown_compressor_lists_registry():
@@ -193,16 +133,104 @@ def test_unknown_prox_lists_registry():
 
 
 def test_compress_energy_threads_to_dense_engine(quad):
-    """CompressionSpec.energy must reach the dense round engine (and
-    round-trip through the legacy config), not silently reset to the
-    default."""
+    """CompressionSpec.energy must reach the dense round engine, not
+    silently reset to the default."""
     spec = FedSpec(rho=1.0, compression=CompressionSpec(
         name="adaptive_topk", ratio=0.25, energy=0.5))
     trainer = build_trainer(quad, spec)
     assert trainer.algo._ecfg.compress_energy == 0.5
-    cfg = FedPLTConfig(compression="adaptive_topk", compress_energy=0.5)
-    assert cfg.to_spec().compression.energy == 0.5
-    assert cfg.to_spec().to_dense_config() == cfg
+
+
+@pytest.mark.parametrize("kw,want", [
+    (dict(compression=CompressionSpec(name="adaptive_topk", energy=0.5)),
+     dict(compress_energy=0.5)),
+    (dict(compression=CompressionSpec(name="topk", backend="pallas")),
+     dict(compress_backend="pallas")),
+    (dict(engine_backend="pallas"), dict(engine_backend="pallas")),
+    (dict(guard_increments=True, guard_norm_bound=9.0),
+     dict(guard_increments=True, guard_norm_bound=9.0)),
+    (dict(aggregator="trimmed_mean", aggregator_param=1.0),
+     dict(aggregator="trimmed_mean", aggregator_param=1.0)),
+    (dict(state_layout="packed"), dict(state_layout="packed")),
+    (dict(async_mode="stale", max_staleness=2),
+     dict(staleness=engine.StalenessConfig(mode="stale",
+                                           max_staleness=2))),
+], ids=["compress-energy", "compress-backend", "engine-backend", "guard",
+        "aggregator", "state-layout", "async"])
+def test_round_knob_reaches_dense_engine(quad, kw, want):
+    """Each round knob goes from the spec to the dense engine through
+    ``FedSpec.round_config`` alone."""
+    spec = FedSpec(rho=1.0, **kw)
+    trainer = build_trainer(quad, spec)
+    ecfg = trainer.algo._ecfg
+    assert ecfg == dataclasses.replace(
+        spec, n_agents=quad.n_agents).round_config()
+    for name, value in want.items():
+        assert getattr(ecfg, name) == value
+
+
+@pytest.mark.parametrize("path", ["dense", "model"])
+def test_zero_participation_agent_keeps_its_state(quad, path):
+    """A per-agent participation tuple on the spec: the agent with rate
+    0 never arrives, so its (x, z) stay at the init over rounds while the
+    others move."""
+    part = (1.0, 1.0, 0.0, 1.0, 1.0)
+    if path == "dense":
+        trainer = build_trainer(quad, FedSpec(rho=1.0, n_epochs=2,
+                                              gamma=0.05,
+                                              participation=part))
+        state = trainer.init(jax.random.PRNGKey(0))
+        x0, z0 = np.asarray(state.x), np.asarray(state.z)
+        for _ in range(4):
+            state = trainer.step(state)
+        x, z = np.asarray(state.x), np.asarray(state.z)
+    else:
+        trainer = build_trainer(QuadModel(), FedSpec(
+            n_agents=5, n_epochs=2, gamma=0.05, participation=part))
+        state = trainer.init(jax.random.PRNGKey(0))
+        x0 = np.asarray(state.x["x"])
+        z0 = np.asarray(state.z["x"])
+        for i in range(4):
+            state, _ = trainer.step(state, _quad_batch(quad),
+                                    jax.random.PRNGKey(i))
+        x, z = np.asarray(state.x["x"]), np.asarray(state.z["x"])
+    np.testing.assert_array_equal(x[2], x0[2])
+    np.testing.assert_array_equal(z[2], z0[2])
+    moved = [k for k in range(5) if k != 2]
+    assert not np.array_equal(x[moved], x0[moved])
+    assert not np.array_equal(z[moved], z0[moved])
+
+
+@pytest.mark.parametrize("part,match", [
+    ((1.0, 0.5), "2 entries for n_agents=3"),
+    ((1.0, 1.5, 0.5), r"must be in \[0, 1\]"),
+    ((1.0, -0.1, 0.5), r"must be in \[0, 1\]"),
+], ids=["wrong-length", "above-one", "negative"])
+def test_per_agent_participation_validated(part, match):
+    with pytest.raises(ValueError, match=match):
+        FedSpec(n_agents=3, gamma=0.05, participation=part).validate()
+
+
+@pytest.mark.parametrize("part", [
+    [1.0, 0.0, 0.5],
+    np.array([1.0, 0.0, 0.5]),
+    jnp.array([1.0, 0.0, 0.5]),
+], ids=["list", "numpy", "jax"])
+def test_per_agent_participation_sequence_is_a_tuple(part):
+    spec = FedSpec(n_agents=3, gamma=0.05, participation=part).validate()
+    assert spec.participation == (1.0, 0.0, 0.5)
+    assert spec.round_config().participation == (1.0, 0.0, 0.5)
+
+
+def test_scalar_participation_string_rejected():
+    with pytest.raises(ValueError, match=r"must be in \(0, 1\]"):
+        FedSpec(n_agents=3, gamma=0.05, participation="0.5").validate()
+
+
+def test_dense_trainer_rejects_agent_count_mismatch(quad):
+    with pytest.raises(ValueError, match="spec.n_agents="):
+        build_trainer(quad, FedSpec(n_agents=quad.n_agents + 1,
+                                    gamma=0.05))
 
 
 # ---------------------------------------------------------------------------

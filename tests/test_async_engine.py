@@ -18,9 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.fedplt import FedPLT, FedPLTConfig
+from repro.core.fedplt import FedPLT
 from repro.core.problem import make_quadratic_problem
-from repro.core.solvers import SolverConfig
 from repro.fed import async_engine, engine, runtime
 from repro.fed.api import (CompressionSpec, FedSpec, PrivacySpec,
                            add_spec_args, build_trainer,
@@ -38,12 +37,14 @@ def quad():
     return make_quadratic_problem(n_agents=N_AGENTS, dim=8, seed=3)
 
 
-def _dense_pair(quad, **kw):
-    base = dict(solver=SolverConfig(name="gd", n_epochs=3, step_size=0.05),
-                participation=0.6, damping=0.7, **kw)
-    sync = FedPLT(quad, FedPLTConfig(**base))
-    asy = FedPLT(quad, FedPLTConfig(**base, async_mode="stale",
-                                    max_staleness=0))
+def _dense_pair(quad, compression="none", **kw):
+    base = dict(solver="gd", n_epochs=3, gamma=0.05, participation=0.6,
+                damping=0.7, **kw,
+                compression=CompressionSpec(name=compression,
+                                            backend="xla"))
+    sync = FedPLT(quad, FedSpec(**base))
+    asy = FedPLT(quad, FedSpec(**base, async_mode="stale",
+                               max_staleness=0))
     return sync, asy
 
 
@@ -128,8 +129,8 @@ def test_k0_bitwise_equals_sync_model(quad, layout, backend, comp):
 @pytest.mark.parametrize("layout,backend", [("tree", "xla"),
                                             ("packed", "pallas")])
 def test_recorded_schedule_replays_bitwise(quad, layout, backend):
-    algo = FedPLT(quad, FedPLTConfig(
-        solver=SolverConfig(name="gd", n_epochs=3, step_size=0.05),
+    algo = FedPLT(quad, FedSpec(
+        solver="gd", n_epochs=3, gamma=0.05,
         participation=0.4, damping=0.7, async_mode="stale",
         max_staleness=3, state_layout=layout, engine_backend=backend))
     key = jax.random.PRNGKey(11)
@@ -146,8 +147,8 @@ def test_recorded_schedule_replays_bitwise(quad, layout, backend):
 
 
 def test_broker_run_replays_bitwise(quad):
-    algo = FedPLT(quad, FedPLTConfig(
-        solver=SolverConfig(name="gd", n_epochs=2, step_size=0.05),
+    algo = FedPLT(quad, FedSpec(
+        solver="gd", n_epochs=2, gamma=0.05,
         damping=0.7, async_mode="stale", max_staleness=2))
     key = jax.random.PRNGKey(5)
     step = lambda s, u: algo.round_with_arrival(s, u)[0]  # noqa: E731
@@ -169,8 +170,8 @@ def test_broker_run_replays_bitwise(quad):
 
 
 def test_broker_k0_is_the_synchronous_barrier(quad):
-    algo = FedPLT(quad, FedPLTConfig(
-        solver=SolverConfig(name="gd", n_epochs=2, step_size=0.05),
+    algo = FedPLT(quad, FedSpec(
+        solver="gd", n_epochs=2, gamma=0.05,
         async_mode="stale", max_staleness=0))
     step = lambda s, u: algo.round_with_arrival(s, u)[0]  # noqa: E731
     broker = IncrementBroker(N_AGENTS, max_staleness=0,
@@ -395,8 +396,7 @@ def test_spec_validate_catches_bad_async_fields():
 
 
 def test_sync_round_rejects_arrival_override(quad):
-    algo = FedPLT(quad, FedPLTConfig(
-        solver=SolverConfig(name="gd", n_epochs=1, step_size=0.05)))
+    algo = FedPLT(quad, FedSpec(solver="gd", n_epochs=1, gamma=0.05))
     with pytest.raises(ValueError, match="require async_mode"):
         algo.round_with_arrival(algo.init(jax.random.PRNGKey(0)),
                                 jnp.ones(N_AGENTS))

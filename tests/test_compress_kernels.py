@@ -272,10 +272,6 @@ def test_backend_threads_to_dense_engine():
         name="topk", backend="pallas"))
     trainer = build_trainer(prob, spec)
     assert trainer.algo._ecfg.compress_backend == "pallas"
-    # legacy shim round-trips the knob too
-    from repro.core.fedplt import FedPLTConfig
-    cfg = FedPLTConfig(compression="topk", compress_backend="pallas")
-    assert cfg.to_spec().compression.backend == "pallas"
 
 
 def test_mixed_dtype_tree_falls_back_per_leaf():

@@ -9,11 +9,13 @@ import pytest
 
 from repro.configs import get_config
 from repro.configs.base import InputShape
-from repro.core.fedplt import FedPLT, FedPLTConfig
+from repro.core.fedplt import FedPLT
 from repro.core.problem import make_quadratic_problem
 from repro.core.solvers import SolverConfig, clip_grad, local_train
 from repro.data.synthetic import make_batch_for
 from repro.fed import engine, runtime
+from repro.fed.api import (CompressionSpec, FedSpec, PrivacySpec,
+                           privacy_report)
 from repro.models.model import build_model
 
 
@@ -106,10 +108,14 @@ def test_dense_round_matches_pre_refactor_bit_for_bit(kw):
     prob = make_quadratic_problem(n_agents=6, dim=5, seed=3)
     rho, ne, rounds = 1.0, 4, 30
     tau = kw.pop("tau", 0.0)
-    solver = SolverConfig(name="noisy_gd" if tau > 0 else "gd",
-                          n_epochs=ne, tau=tau)
-    cfg = FedPLTConfig(rho=rho, solver=solver, **kw)
-    algo = FedPLT(prob, cfg)
+    spec = FedSpec(
+        rho=rho, solver="noisy_gd" if tau > 0 else "gd", n_epochs=ne,
+        participation=kw.get("participation", 1.0),
+        damping=kw.get("damping", 1.0), privacy=PrivacySpec(tau=tau),
+        compression=CompressionSpec(
+            name=kw.get("compression", "none"),
+            ratio=kw.get("compress_ratio", 0.25), backend="xla"))
+    algo = FedPLT(prob, spec)
     key = jax.random.PRNGKey(7)
 
     state = algo.init(key)
@@ -202,10 +208,10 @@ def setup():
     return cfg, model
 
 
-def _losses(cfg, model, fcfg, rounds=6):
-    state = runtime.init_state(model, jax.random.PRNGKey(0), fcfg)
-    step = jax.jit(runtime.make_train_step(model, fcfg))
-    batch = make_batch_for(cfg, SHAPE, n_agents=fcfg.n_agents)
+def _losses(cfg, model, spec, rounds=6):
+    state = runtime.init_state(model, jax.random.PRNGKey(0), spec)
+    step = jax.jit(runtime.make_train_step(model, spec))
+    batch = make_batch_for(cfg, SHAPE, n_agents=spec.n_agents)
     out = []
     for i in range(rounds):
         state, m = step(state, batch, jax.random.PRNGKey(i))
@@ -217,9 +223,10 @@ def _losses(cfg, model, fcfg, rounds=6):
 def test_compressed_pytree_round_converges(setup, comp):
     """Model-scale smoke: a compressed z-exchange still trains."""
     cfg, model = setup
-    fcfg = runtime.FedConfig(n_agents=2, n_epochs=2, gamma=0.1,
-                             compression=comp, compress_ratio=0.5)
-    losses, state = _losses(cfg, model, fcfg)
+    spec = FedSpec(n_agents=2, n_epochs=2, gamma=0.1,
+                   compression=CompressionSpec(name=comp, ratio=0.5,
+                                               backend="xla"))
+    losses, state = _losses(cfg, model, spec)
     assert losses[-1] < losses[0]
     assert state.t is not None  # coordinator copy materialized
     # t lags z (error feedback residual is nonzero under top-k)
@@ -234,18 +241,16 @@ def test_compressed_pytree_round_converges(setup, comp):
 
 def test_agd_solver_at_model_scale(setup):
     cfg, model = setup
-    fcfg = runtime.FedConfig(n_agents=2, n_epochs=3, gamma=0.05,
-                             solver="agd")
-    losses, _ = _losses(cfg, model, fcfg)
+    spec = FedSpec(n_agents=2, n_epochs=3, gamma=0.05, solver="agd")
+    losses, _ = _losses(cfg, model, spec)
     assert losses[-1] < losses[0]
 
 
 def test_pallas_fused_step_matches_unfused(setup):
     cfg, model = setup
-    base = runtime.FedConfig(n_agents=2, n_epochs=2, gamma=0.1)
+    base = FedSpec(n_agents=2, n_epochs=2, gamma=0.1)
     losses_ref, state_ref = _losses(cfg, model, base, rounds=2)
-    fused = runtime.FedConfig(n_agents=2, n_epochs=2, gamma=0.1,
-                              use_pallas_update=True)
+    fused = FedSpec(n_agents=2, n_epochs=2, gamma=0.1, use_pallas=True)
     losses_fused, state_fused = _losses(cfg, model, fused, rounds=2)
     np.testing.assert_allclose(losses_ref, losses_fused, rtol=1e-4)
     x_ref = jax.tree_util.tree_leaves(state_ref.x)[0]
@@ -255,13 +260,13 @@ def test_pallas_fused_step_matches_unfused(setup):
 
 
 def test_privacy_report_threads_from_config():
-    fcfg = runtime.FedConfig(n_agents=4, rho=1.0, gamma=0.05, n_epochs=3,
-                             tau=0.1, clip=1.0)
-    rep = runtime.privacy_report(fcfg, n_rounds=50, local_dataset_size=100)
+    spec = FedSpec(n_agents=4, rho=1.0, gamma=0.05, n_epochs=3,
+                   privacy=PrivacySpec(tau=0.1, clip=1.0))
+    rep = privacy_report(spec, n_rounds=50, local_dataset_size=100)
     assert np.isfinite(rep.adp_eps) and rep.adp_eps > 0
     assert rep.adp_eps <= rep.eps_ceiling + 1e-9
     with pytest.raises(ValueError):
-        runtime.privacy_report(runtime.FedConfig(tau=0.0), 10, 10)
+        privacy_report(FedSpec(n_agents=16, gamma=0.05), 10, 10)
 
 
 def test_fed_state_specs_structure():

@@ -6,12 +6,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.fedplt import FedPLT, FedPLTConfig
+from repro.core.fedplt import FedPLT
 from repro.core.metrics import hitting_round
 from repro.core.problem import LogRegProblem, make_logreg_problem
-from repro.core.solvers import SolverConfig
+from repro.fed.api import CompressionSpec, FedSpec
 
-GD5 = SolverConfig(name="gd", n_epochs=5)
+
+def _comp(name, ratio=0.25):
+    return CompressionSpec(name=name, ratio=ratio, backend="xla")
 
 
 @pytest.fixture(scope="module")
@@ -26,16 +28,15 @@ def hetero_prob():
     return LogRegProblem(A=p0.A * scales, b=p0.b, eps=0.5)
 
 
-def _run(p, cfg, rounds=500):
-    _, crit = FedPLT(p, cfg).run(jax.random.PRNGKey(0), rounds)
+def _run(p, spec, rounds=500):
+    _, crit = FedPLT(p, spec).run(jax.random.PRNGKey(0), rounds)
     return np.asarray(crit)
 
 
 def test_uncoordinated_solvers_converge(hetero_prob):
     """Remark 1: per-agent step sizes from local moduli still converge
     exactly on heterogeneous agents."""
-    crit = _run(hetero_prob, FedPLTConfig(rho=1.0, uncoordinated=True,
-                                          solver=GD5), 300)
+    crit = _run(hetero_prob, FedSpec(rho=1.0, uncoordinated=True), 300)
     assert crit[-1] < 1e-9
 
 
@@ -46,35 +47,32 @@ def test_per_agent_moduli_vary(hetero_prob):
 
 @pytest.mark.parametrize("comp,kw,rounds", [
     ("int8", {}, 300),
-    ("topk", {"compress_ratio": 0.5}, 400),
-    ("topk", {"compress_ratio": 0.1}, 800),
+    ("topk", {"ratio": 0.5}, 400),
+    ("topk", {"ratio": 0.1}, 800),
 ])
 def test_compressed_exchange_converges_exactly(prob, comp, kw, rounds):
     """Beyond-paper: lag-based error feedback keeps exact convergence
     under int8 and top-k (down to 10%) z compression."""
-    cfg = FedPLTConfig(rho=1.0, compression=comp, solver=GD5, **kw)
-    crit = _run(prob, cfg, rounds)
+    crit = _run(prob, FedSpec(rho=1.0, compression=_comp(comp, **kw)),
+                rounds)
     assert crit[-1] < 1e-9, crit[-1]
 
 
 def test_compression_costs_rounds_not_accuracy(prob):
-    hit_exact = hitting_round(_run(prob, FedPLTConfig(rho=1.0,
-                                                      solver=GD5), 300))
-    hit_topk = hitting_round(_run(prob, FedPLTConfig(
-        rho=1.0, compression="topk", compress_ratio=0.1,
-        solver=GD5), 800))
+    hit_exact = hitting_round(_run(prob, FedSpec(rho=1.0), 300))
+    hit_topk = hitting_round(_run(prob, FedSpec(
+        rho=1.0, compression=_comp("topk", 0.1)), 800))
     assert hit_exact < hit_topk            # bandwidth traded for rounds
     assert hit_topk < 10 * hit_exact       # at sublinear cost
 
 
 def test_damping_half_is_douglas_rachford(prob):
     """damping=1/2 (DRS) still converges exactly, slower than PRS."""
-    crit = _run(prob, FedPLTConfig(rho=1.0, damping=0.5, solver=GD5), 400)
+    crit = _run(prob, FedSpec(rho=1.0, damping=0.5), 400)
     assert crit[-1] < 1e-9
 
 
 def test_compression_with_partial_participation(prob):
-    cfg = FedPLTConfig(rho=1.0, compression="int8", participation=0.6,
-                       solver=GD5)
-    crit = _run(prob, cfg, 800)
+    spec = FedSpec(rho=1.0, compression=_comp("int8"), participation=0.6)
+    crit = _run(prob, spec, 800)
     assert crit[-1] < 1e-8

@@ -17,6 +17,7 @@ Plus the crash-safe checkpoint layer (atomic tmp-then-rename saves,
 key-set validation, resume-bitwise) that rides in the same PR.
 """
 
+import dataclasses
 import json
 import math
 import os
@@ -28,12 +29,12 @@ import pytest
 
 from repro.checkpoint import (find_latest_checkpoint, is_checkpoint,
                               restore_checkpoint, save_checkpoint)
-from repro.core.fedplt import FedPLT, FedPLTConfig
+from repro.core.fedplt import FedPLT
 from repro.core.problem import make_quadratic_problem
-from repro.core.solvers import SolverConfig
 from repro.fed import async_engine, engine, runtime
-from repro.fed.api import (FedSpec, PrivacySpec, build_trainer,
-                           effective_privacy_report, spec_from_args)
+from repro.fed.api import (CompressionSpec, FedSpec, PrivacySpec,
+                           build_trainer, effective_privacy_report,
+                           spec_from_args)
 from repro.fed.broker import ArrivalSchedule, IncrementBroker, replay
 from repro.fed.engine import RoundConfig
 from repro.fed.faults import FaultEvent, FaultPlan, FaultRecord
@@ -51,11 +52,12 @@ def quad():
     return make_quadratic_problem(n_agents=N_AGENTS, dim=8, seed=3)
 
 
-def _algo(quad, **kw):
-    base = dict(solver=SolverConfig(name="gd", n_epochs=2,
-                                    step_size=0.05), damping=0.7)
+def _algo(quad, compression="none", **kw):
+    base = dict(solver="gd", n_epochs=2, gamma=0.05, damping=0.7,
+                compression=CompressionSpec(name=compression,
+                                            backend="xla"))
     base.update(kw)
-    return FedPLT(quad, FedPLTConfig(**base))
+    return FedPLT(quad, FedSpec(**base))
 
 
 def _assert_state_equal(a, b, fields=("x", "z", "y")):
@@ -336,9 +338,7 @@ def test_quarantine_equals_non_arrival_sync(quad, layout, backend):
     kw = dict(state_layout=layout, engine_backend=backend,
               guard_increments=True)
     a = _algo(quad, **kw)
-    b = FedPLT(quad, FedPLTConfig(
-        solver=SolverConfig(name="gd", n_epochs=2, step_size=0.05),
-        damping=0.7, **kw), participation=(1.0, 1.0, 0.0, 1.0))
+    b = _algo(quad, participation=(1.0, 1.0, 0.0, 1.0), **kw)
     corrupt = np.zeros(N_AGENTS, np.float32)
     corrupt[2] = np.nan
     sa, sb = a.init(key), b.init(key)
@@ -382,10 +382,8 @@ def test_norm_bound_guard_vs_finiteness_only(quad):
     corrupt[0] = 1e4          # large but finite: norm-bound territory
     bounded = _algo(quad, guard_increments=True, guard_norm_bound=100.0)
     unbounded = _algo(quad, guard_increments=True)   # inf: finite-only
-    dropped = FedPLT(quad, FedPLTConfig(
-        solver=SolverConfig(name="gd", n_epochs=2, step_size=0.05),
-        damping=0.7, guard_increments=True, guard_norm_bound=100.0),
-        participation=(0.0, 1.0, 1.0, 1.0))
+    dropped = _algo(quad, guard_increments=True, guard_norm_bound=100.0,
+                    participation=(0.0, 1.0, 1.0, 1.0))
     s_b, _ = bounded.round_with_faults(bounded.init(key), None,
                                        jnp.asarray(corrupt), None)
     s_d, _ = dropped.round_with_faults(dropped.init(key), None, None,
@@ -442,13 +440,12 @@ def test_quarantine_equals_non_arrival_sharded(shards):
     prob = make_quadratic_problem(n_agents=8, dim=8, seed=1)
     mesh = Mesh(np.asarray(jax.devices()[:shards]).reshape(shards, 1),
                 ("agent", "model"))
-    cfg = FedPLTConfig(
-        solver=SolverConfig(name="gd", n_epochs=2, step_size=0.05),
-        damping=0.7, state_layout="packed", engine_backend="pallas",
-        guard_increments=True)
-    a = FedPLT(prob, cfg, mesh=mesh)
-    b = FedPLT(prob, cfg, mesh=mesh,
-               participation=(1.0,) * 5 + (0.0,) + (1.0,) * 2)
+    spec = FedSpec(solver="gd", n_epochs=2, gamma=0.05, damping=0.7,
+                   state_layout="packed", engine_backend="pallas",
+                   guard_increments=True)
+    a = FedPLT(prob, spec, mesh=mesh)
+    b = FedPLT(prob, dataclasses.replace(
+        spec, participation=(1.0,) * 5 + (0.0,) + (1.0,) * 2), mesh=mesh)
     corrupt = np.zeros(8, np.float32)
     corrupt[5] = np.nan
     key = jax.random.PRNGKey(3)
@@ -472,8 +469,8 @@ def test_broker_crash_eviction_completes_and_replays_bitwise(quad):
     # after the retry budget, and the (schedule, record) pair replays
     # the whole trajectory bit-for-bit
     prob = make_quadratic_problem(n_agents=64, dim=4, seed=0)
-    algo = FedPLT(prob, FedPLTConfig(
-        solver=SolverConfig(name="gd", n_epochs=1, step_size=0.05),
+    algo = FedPLT(prob, FedSpec(
+        solver="gd", n_epochs=1, gamma=0.05,
         damping=0.7, async_mode="stale", max_staleness=1))
     plan = FaultPlan((FaultEvent("crash", 3, 2),
                       FaultEvent("crash", 7, 2)))
@@ -676,8 +673,8 @@ def test_broker_zero_rounds(quad):
 
 def test_broker_single_agent_with_staleness():
     prob = make_quadratic_problem(n_agents=1, dim=4, seed=0)
-    algo = FedPLT(prob, FedPLTConfig(
-        solver=SolverConfig(name="gd", n_epochs=1, step_size=0.05),
+    algo = FedPLT(prob, FedSpec(
+        solver="gd", n_epochs=1, gamma=0.05,
         async_mode="stale", max_staleness=2))
     broker = IncrementBroker(1, max_staleness=2, grace=0.01,
                              latency_fn=lambda a, r: 0.001)
@@ -792,9 +789,6 @@ def test_guard_knobs_thread_through_every_front_end(quad):
     # defaults stay off (and are bitwise no-ops -- tested above)
     assert not spec_from_args([]).guard_increments
     assert math.isinf(spec_from_args([]).guard_norm_bound)
-    fcfg = runtime.FedConfig(guard_increments=True, guard_norm_bound=9.0)
-    s2 = fcfg.to_spec()
-    assert s2.guard_increments and s2.guard_norm_bound == 9.0
 
 
 def test_guard_bound_validation():

@@ -9,8 +9,8 @@ import pytest
 from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.data.synthetic import make_batch_for
-from repro.fed import runtime
-from repro.fed.api import CompressionSpec, FedSpec, build_trainer
+from repro.fed import engine, runtime
+from repro.fed.api import CompressionSpec, FedSpec, PrivacySpec, build_trainer
 from repro.models.model import build_model
 
 SHAPE = InputShape("tiny", 32, 8, "train")
@@ -25,9 +25,9 @@ def setup():
 
 def test_fed_training_reduces_loss(setup):
     cfg, model = setup
-    fcfg = runtime.FedConfig(n_agents=4, n_epochs=2, gamma=0.1)
-    state = runtime.init_state(model, jax.random.PRNGKey(0), fcfg)
-    step = jax.jit(runtime.make_train_step(model, fcfg))
+    spec = FedSpec(n_agents=4, n_epochs=2, gamma=0.1)
+    state = runtime.init_state(model, jax.random.PRNGKey(0), spec)
+    step = jax.jit(runtime.make_train_step(model, spec))
     batch = make_batch_for(cfg, SHAPE, n_agents=4)
     losses = []
     for i in range(6):
@@ -38,9 +38,10 @@ def test_fed_training_reduces_loss(setup):
 
 def test_dp_noise_and_clipping_path(setup):
     cfg, model = setup
-    fcfg = runtime.FedConfig(n_agents=2, n_epochs=2, tau=0.01, clip=1.0)
-    state = runtime.init_state(model, jax.random.PRNGKey(0), fcfg)
-    step = jax.jit(runtime.make_train_step(model, fcfg))
+    spec = FedSpec(n_agents=2, n_epochs=2, gamma=0.05,
+                   privacy=PrivacySpec(tau=0.01, clip=1.0))
+    state = runtime.init_state(model, jax.random.PRNGKey(0), spec)
+    step = jax.jit(runtime.make_train_step(model, spec))
     batch = make_batch_for(cfg, SHAPE, n_agents=2)
     state, m = step(state, batch, jax.random.PRNGKey(0))
     assert np.isfinite(m["loss"])
@@ -53,10 +54,10 @@ def test_dp_noise_and_clipping_path(setup):
 
 def test_inactive_agents_keep_state(setup):
     cfg, model = setup
-    fcfg = runtime.FedConfig(n_agents=4, n_epochs=1,
-                             participation=1e-7)  # nobody active
-    state0 = runtime.init_state(model, jax.random.PRNGKey(0), fcfg)
-    step = jax.jit(runtime.make_train_step(model, fcfg))
+    spec = FedSpec(n_agents=4, n_epochs=1, gamma=0.05,
+                   participation=1e-7)  # nobody active
+    state0 = runtime.init_state(model, jax.random.PRNGKey(0), spec)
+    step = jax.jit(runtime.make_train_step(model, spec))
     batch = make_batch_for(cfg, SHAPE, n_agents=4)
     state1, m = step(state0, batch, jax.random.PRNGKey(3))
     same = jax.tree_util.tree_all(jax.tree_util.tree_map(
@@ -67,9 +68,10 @@ def test_inactive_agents_keep_state(setup):
 
 def test_weight_decay_prox_shrinks(setup):
     cfg, model = setup
-    fcfg = runtime.FedConfig(n_agents=2, weight_decay=0.1, rho=1.0)
-    zbar = {"w": jnp.ones((3,))}
-    y = runtime._coordinator_prox(zbar, fcfg)
+    spec = FedSpec(n_agents=2, gamma=0.05, weight_decay=0.1, rho=1.0)
+    z = {"w": jnp.ones((2, 3))}
+    y = engine.coordinator_prox(z, spec.round_config(),
+                                spec.resolve_prox_h())
     expect = 1.0 / (1.0 + 1.0 * 0.1 / 2)
     np.testing.assert_allclose(y["w"], expect, atol=1e-6)
 
@@ -77,9 +79,8 @@ def test_weight_decay_prox_shrinks(setup):
 def test_runtime_matches_core_fedplt_on_quadratic():
     """The pytree runtime round == the paper-faithful core round when the
     'model' is a bare quadratic loss (full participation, no noise)."""
-    from repro.core.fedplt import FedPLT, FedPLTConfig
+    from repro.core.fedplt import FedPLT
     from repro.core.problem import make_quadratic_problem
-    from repro.core.solvers import SolverConfig
 
     prob = make_quadratic_problem(n_agents=3, dim=4, seed=0)
 
@@ -93,16 +94,15 @@ def test_runtime_matches_core_fedplt_on_quadratic():
             return 0.5 * x @ Q @ x + c @ x
 
     gamma, rho, ne = 0.05, 1.0, 3
-    fcfg = runtime.FedConfig(n_agents=3, rho=rho, gamma=gamma, n_epochs=ne)
-    state = runtime.init_state(QuadModel(), jax.random.PRNGKey(0), fcfg)
-    step = runtime.make_train_step(QuadModel(), fcfg)
+    spec = FedSpec(n_agents=3, rho=rho, gamma=gamma, n_epochs=ne)
+    state = runtime.init_state(QuadModel(), jax.random.PRNGKey(0), spec)
+    step = runtime.make_train_step(QuadModel(), spec)
     batch = {"Q": prob.Q, "c": prob.c}
     for i in range(50):
         state, _ = step(state, batch, jax.random.PRNGKey(i))
 
-    core = FedPLT(prob, FedPLTConfig(
-        rho=rho, solver=SolverConfig(name="gd", n_epochs=ne,
-                                     step_size=gamma)))
+    core = FedPLT(prob, FedSpec(rho=rho, solver="gd", n_epochs=ne,
+                                gamma=gamma))
     cstate, _ = core.run(jax.random.PRNGKey(0), 50)
     np.testing.assert_allclose(
         jnp.mean(state.x["x"], axis=0), core.x_bar(cstate), atol=1e-3)

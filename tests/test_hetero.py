@@ -4,16 +4,15 @@ privacy table.
 
 The safety contract that makes the feature cheap to adopt: a
 *homogeneous* agent-group spec (one full-size group, knobs inherited)
-is bit-identical to the legacy ungrouped path on every configuration
-class (dense gd/agd, DP noise, compressed uplink, partial
-participation)."""
+is bit-identical to the ungrouped spec on every configuration class
+(dense gd/agd, DP noise, compressed uplink, partial participation)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.fedplt import FedPLT, FedPLTConfig
+from repro.core.fedplt import FedPLT
 from repro.core.problem import make_quadratic_problem
 from repro.core.solvers import SolverConfig
 from repro.fed import runtime
@@ -191,27 +190,25 @@ def test_run_solvers_accepts_bare_solver_group():
 
 
 # ---------------------------------------------------------------------------
-# Homogeneous agent_groups == legacy path, bit for bit (dense)
+# Homogeneous agent_groups == the ungrouped spec, bit for bit (dense)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("cfg_kw,solver_kw,spec_kw", [
-    (dict(), dict(name="gd"), dict()),
-    (dict(), dict(name="agd"), dict(solver="agd")),
-    (dict(), dict(name="noisy_gd", tau=0.05),
-     dict(privacy=PrivacySpec(tau=0.05))),
-    (dict(participation=0.6), dict(name="gd"), dict(participation=0.6)),
-    (dict(participation=0.7, compression="topk", compress_ratio=0.5,
-          damping=0.5), dict(name="gd"),
-     dict(participation=0.7, damping=0.5,
-          compression=CompressionSpec(name="topk", ratio=0.5))),
-])
-def test_single_homogeneous_group_bit_identical(quad, cfg_kw, solver_kw,
-                                                spec_kw):
-    cfg = FedPLTConfig(rho=1.0,
-                       solver=SolverConfig(n_epochs=3, **solver_kw),
-                       **cfg_kw)
+HOMOGENEOUS_CASES = [
+    dict(),
+    dict(solver="agd"),
+    dict(privacy=PrivacySpec(tau=0.05)),
+    dict(participation=0.6),
+    dict(participation=0.7, damping=0.5,
+         compression=CompressionSpec(name="topk", ratio=0.5)),
+]
+
+
+@pytest.mark.parametrize("spec_kw", HOMOGENEOUS_CASES,
+                         ids=["gd", "agd", "dp", "partial", "topk"])
+def test_single_homogeneous_group_bit_identical(quad, spec_kw):
     key = jax.random.PRNGKey(11)
-    s_ref, c_ref = FedPLT(quad, cfg).run(key, 25)
+    s_ref, c_ref = FedPLT(quad, FedSpec(rho=1.0, n_epochs=3,
+                                        **spec_kw)).run(key, 25)
     spec = FedSpec(rho=1.0, n_epochs=3,
                    agent_groups=(AgentGroupSpec(size=quad.n_agents),),
                    **spec_kw)
@@ -224,9 +221,8 @@ def test_single_homogeneous_group_bit_identical(quad, cfg_kw, solver_kw,
 def test_multi_group_homogeneous_matches_legacy_closely(quad):
     """Two groups with identical knobs are the same algorithm; only the
     batched-slice op scheduling may differ, so allclose not bit-equal."""
-    cfg = FedPLTConfig(rho=1.0, solver=SolverConfig(name="gd", n_epochs=3))
     key = jax.random.PRNGKey(11)
-    _, c_ref = FedPLT(quad, cfg).run(key, 20)
+    _, c_ref = FedPLT(quad, FedSpec(rho=1.0, n_epochs=3)).run(key, 20)
     _, c_new = build_trainer(
         quad, FedSpec(n_epochs=3, agent_groups="2,3")).run(key, 20)
     np.testing.assert_allclose(np.asarray(c_new), np.asarray(c_ref),

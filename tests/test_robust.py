@@ -25,7 +25,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core.fedplt import FedPLT, FedPLTConfig
+from repro.core.fedplt import FedPLT
 from repro.core.problem import make_quadratic_problem
 from repro.core.solvers import SolverConfig
 from repro.fed import async_engine
@@ -144,9 +144,6 @@ def test_spec_and_config_threading():
     assert cfg.aggregator == "trimmed_mean"
     assert cfg.aggregator_param == 2.0
     assert cfg.robust_aggregator == "trimmed_mean"
-    dense = spec.to_dense_config()
-    assert dense.aggregator == "trimmed_mean"
-    assert dense.to_spec(8).aggregator == "trimmed_mean"
     with pytest.raises(ValueError, match="2f < N"):
         FedSpec(n_agents=4, aggregator="trimmed_mean",
                 aggregator_param=2).validate()
@@ -384,8 +381,8 @@ def test_async_engine_takes_robust_aggregator():
 
 def _attack_run(aggregator, param, corrupt, rounds=60):
     quad = make_quadratic_problem(n_agents=8, dim=8, seed=3)
-    algo = FedPLT(quad, FedPLTConfig(
-        solver=SolverConfig(name="gd", n_epochs=2, step_size=0.05),
+    algo = FedPLT(quad, FedSpec(
+        solver="gd", n_epochs=2, gamma=0.05,
         damping=0.7, aggregator=aggregator, aggregator_param=param))
     s = algo.init(jax.random.PRNGKey(0))
     for _ in range(rounds):
@@ -424,8 +421,8 @@ def test_byzantine_broker_end_to_end_with_replay():
     assert plan.has_byzantine
 
     def build(aggregator, param):
-        algo = FedPLT(quad, FedPLTConfig(
-            solver=SolverConfig(name="gd", n_epochs=2, step_size=0.05),
+        algo = FedPLT(quad, FedSpec(
+            solver="gd", n_epochs=2, gamma=0.05,
             damping=0.7, async_mode="stale", max_staleness=0,
             aggregator=aggregator, aggregator_param=param))
         return algo, lambda s, u, c, l: algo.round_with_faults(

@@ -333,20 +333,6 @@ def test_cli_engine_backend_roundtrip():
     assert spec.validate().round_config().engine_backend == "pallas"
 
 
-def test_engine_backend_threads_through_shims():
-    from repro.core.fedplt import FedPLTConfig
-    from repro.fed.runtime import FedConfig
-
-    cfg = FedPLTConfig(engine_backend="pallas")
-    assert cfg.to_spec().engine_backend == "pallas"
-    assert cfg.to_spec(n_agents=2).round_config().engine_backend == \
-        "pallas"
-    spec = cfg.to_spec()
-    assert spec.to_dense_config().engine_backend == "pallas"
-    fcfg = FedConfig(engine_backend="pallas")
-    assert fcfg.to_spec().engine_backend == "pallas"
-
-
 def test_backend_threads_to_dense_engine():
     prob = make_logreg_problem(n_agents=4, q=20, dim=10, seed=0)
     trainer = build_trainer(prob, FedSpec(engine_backend="pallas"))

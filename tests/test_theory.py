@@ -6,9 +6,10 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import theory
-from repro.core.fedplt import FedPLT, FedPLTConfig
+from repro.core.fedplt import FedPLT
 from repro.core.problem import make_quadratic_problem
 from repro.core.solvers import SolverConfig
+from repro.fed.api import FedSpec
 
 
 def test_zeta_minimized_near_rho_star():
@@ -49,7 +50,7 @@ def test_s_norm_bounds_empirical_rate():
     rho, ne = 1.0, 5
     scfg = SolverConfig(name="gd", n_epochs=ne)
     s_norm = theory.s_norm(scfg, mu, L, rho)
-    algo = FedPLT(prob, FedPLTConfig(rho=rho, solver=scfg))
+    algo = FedPLT(prob, FedSpec(rho=rho, solver="gd", n_epochs=ne))
     state, crit = algo.run(jax.random.PRNGKey(0), 80)
     crit = np.asarray(crit)
     # empirical per-round criterion decay rate (criterion ~ dist^2)
